@@ -11,9 +11,13 @@ compiled program.  This bench measures, per backend:
               proof that the cache removes recompiles from serving)
 
 Rows are saved to BENCH_runtime.json (``main(save=...)`` / run.py).  The
-mesh backend needs one device per worker, so its rows come from a child
-interpreter with 8 fake CPU devices; absolute times are CPU-interpret
-numbers, the cold/warm RATIO is the signal.
+mesh backend needs one device per worker (K=4): with that many devices the
+mesh rows run in this process; on a CPU host with fewer they come from a
+child interpreter with 8 fake CPU devices, and a failed child fails the
+run.  A TPU host with fewer chips cannot give a child the chip this
+process holds, so there the mesh rows are an error.  Off the chip,
+absolute times are CPU-interpret numbers and the cold/warm RATIO is the
+signal.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ from pathlib import Path
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 LOCAL_BACKENDS = ("reference", "staged", "fused")
 _MESH_FLAG = "--mesh-json"
@@ -90,14 +95,16 @@ def run_local() -> list:
         return rows
 
 
-def run_mesh_child() -> list:
-    """Executed inside the child (8 fake devices): mesh-backend rows."""
+def run_mesh() -> list:
+    """Mesh-backend rows, one coded worker per device of a (n/4, 4) mesh."""
     from repro.core.numerics import enable_x64
     from repro.runtime import CodedMatmul
 
     with enable_x64():
         plan, A, B = _problem()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        n_dev = len(jax.devices())
+        mesh = jax.make_mesh((n_dev // plan.K, plan.K), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cm = CodedMatmul(plan, "mesh", mesh=mesh, dtype=jax.numpy.float64)
         row = bench_backend(cm, A, B)
         assert row["executables"] == row["builds"], row
@@ -106,7 +113,14 @@ def run_mesh_child() -> list:
 
 def run() -> list:
     rows = run_local()
-    rows.extend(_mesh_rows_via_subprocess())
+    if len(jax.devices()) >= 4:
+        rows.extend(run_mesh())
+    elif jax.default_backend() == "cpu":
+        rows.extend(_mesh_rows_via_subprocess())
+    else:
+        raise SystemExit(
+            f"mesh rows need 4 {jax.default_backend()} devices, have "
+            f"{len(jax.devices())}")
     return rows
 
 
@@ -120,8 +134,7 @@ def _mesh_rows_via_subprocess() -> list:
         [sys.executable, "-m", "benchmarks.runtime_bench", _MESH_FLAG],
         env=env, cwd=root, capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
-        print(f"mesh rows skipped (child failed):\n{proc.stderr[-500:]}")
-        return []
+        raise RuntimeError(f"mesh child failed:\n{proc.stderr[-2000:]}")
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
@@ -145,7 +158,7 @@ def main(save: str | None = None):
 
 if __name__ == "__main__":
     if _MESH_FLAG in sys.argv:
-        print(json.dumps(run_mesh_child()))
+        print(json.dumps(run_mesh()))
     else:
         save = None if "--no-save" in sys.argv else "BENCH_runtime.json"
         if "--save" in sys.argv:

@@ -2,7 +2,6 @@
 from repro.core.api import (
     CodedMatmulPlan,
     coded_matmul,
-    encode_blocks,
     extend_plan,
     fused_worker_products,
     make_plan,
@@ -40,7 +39,7 @@ from repro.core.simulator import (
 )
 
 __all__ = [
-    "CodedMatmulPlan", "coded_matmul", "encode_blocks", "make_plan",
+    "CodedMatmulPlan", "coded_matmul", "make_plan",
     "uncoded_matmul", "worker_products", "fused_worker_products",
     "extend_plan", "shrink_plan",
     "BoundsReport", "choose_s", "conservative_L", "plan_p_prime",

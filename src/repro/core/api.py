@@ -1,7 +1,7 @@
 """Plan construction + the encode/product building blocks.
 
 ``CodedMatmulPlan`` freezes everything static about one coded matmul;
-``encode_blocks`` / ``worker_products`` / ``fused_worker_products`` are the
+``worker_products`` / ``fused_worker_products`` are the encode + product
 stage primitives the runtime executors are built from.
 
 ``coded_matmul`` remains as a deprecation shim over the unified runtime
@@ -14,16 +14,18 @@ import dataclasses
 import warnings
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bounds as bounds_mod
-from repro.core.decoding import DecodePanelCache
+from repro.core.decoding import DecodePanelCache, apply_weights
+from repro.core.numerics import precise_matmul_t
 from repro.core.points import make_points
 from repro.core.schemes import Scheme, make_scheme
 
 __all__ = ["CodedMatmulPlan", "make_plan", "extend_plan", "shrink_plan",
-           "coded_matmul", "encode_blocks", "worker_products",
+           "coded_matmul", "worker_products",
            "fused_worker_products", "runtime_facade"]
 
 
@@ -173,19 +175,35 @@ def shrink_plan(plan: CodedMatmulPlan, keep: Sequence[int]) -> CodedMatmulPlan:
         coeff_a=plan.coeff_a[idx], coeff_b=plan.coeff_b[idx])
 
 
-def encode_blocks(plan: CodedMatmulPlan, a_blocks: jnp.ndarray, b_blocks: jnp.ndarray):
-    """a_blocks: (p, m, bv, br), b_blocks: (p, n, bv, bt)
-    -> (K, bv, br), (K, bv, bt) coded matrices per worker."""
-    ca = jnp.asarray(plan.coeff_a, dtype=_coeff_dtype(a_blocks, plan))
-    cb = jnp.asarray(plan.coeff_b, dtype=_coeff_dtype(b_blocks, plan))
-    a_tilde = jnp.einsum("kpm,pmvr->kvr", ca, a_blocks.astype(ca.dtype))
-    b_tilde = jnp.einsum("kpn,pnvt->kvt", cb, b_blocks.astype(cb.dtype))
-    return a_tilde, b_tilde
+def worker_products(plan: CodedMatmulPlan, a_blocks: jnp.ndarray,
+                    b_blocks: jnp.ndarray) -> jnp.ndarray:
+    """All worker products Y_k = A~_k^T B~_k in plain XLA.
 
+    a_blocks: (p, m, bv, br), b_blocks: (p, n, bv, bt) -> (K, br, bt).
+    Worker k encodes its coded pair A~_k, B~_k as a weighted sum of the
+    source blocks (``decoding.apply_weights``, elementwise) and multiplies
+    them.  The workers run one at a time (``lax.map`` over K), so the only
+    dot is the one (bv, br)^T (bv, bt) product live at a time.  That keeps
+    the f64 pipeline at the paper's 8000^3 inside one TPU chip's HBM.  The
+    product is ``numerics.precise_matmul_t``: on a TPU, where XLA's
+    emulated f64 dot falls short of f64, it is a sum of exact int8 slice
+    products.
+    """
+    p, m, bv, br = a_blocks.shape
+    _, n, _, bt = b_blocks.shape
+    ca = jnp.asarray(plan.coeff_a.reshape(plan.K, 1, p * m),
+                     dtype=_coeff_dtype(a_blocks, plan))
+    cb = jnp.asarray(plan.coeff_b.reshape(plan.K, 1, p * n),
+                     dtype=_coeff_dtype(b_blocks, plan))
+    a_flat = a_blocks.reshape(p * m, bv, br)
+    b_flat = b_blocks.reshape(p * n, bv, bt)
 
-def worker_products(a_tilde: jnp.ndarray, b_tilde: jnp.ndarray) -> jnp.ndarray:
-    """Per-worker products Y_k = A~_k^T B~_k: (K, bv, br), (K, bv, bt) -> (K, br, bt)."""
-    return jnp.einsum("kvr,kvt->krt", a_tilde, b_tilde)
+    def one_worker(coeffs):
+        ca_k, cb_k = coeffs                       # (1, p*m), (1, p*n)
+        return precise_matmul_t(apply_weights(ca_k, a_flat)[0],
+                                apply_weights(cb_k, b_flat)[0])
+
+    return jax.lax.map(one_worker, (ca, cb))
 
 
 def fused_worker_products(plan: CodedMatmulPlan, a_blocks: jnp.ndarray,
@@ -193,7 +211,7 @@ def fused_worker_products(plan: CodedMatmulPlan, a_blocks: jnp.ndarray,
     """All worker products via the fused encode+product Pallas megakernel.
 
     a_blocks: (p, m, bv, br), b_blocks: (p, n, bv, bt) -> (K, br, bt).
-    Equivalent to encode_blocks + worker_products but the coded matrices
+    Equivalent to worker_products but the coded matrices
     A~, B~ are formed only tile-wise in VMEM, never written to HBM.
     """
     from repro.kernels import ops as kops

@@ -28,7 +28,7 @@ from repro.core.vandermonde import interpolate_solve, interpolate_masked
 __all__ = [
     "digit_extract", "decode", "decode_masked",
     "DecodePanel", "DecodePanelCache", "make_decode_panel",
-    "decode_with_panel", "decode_with_weights",
+    "decode_with_panel", "decode_with_weights", "apply_weights",
 ]
 
 
@@ -153,6 +153,24 @@ def make_decode_panel(scheme: Scheme, z_all: np.ndarray,
     return DecodePanel(mask=m, W=np.asarray(W_full[useful]))
 
 
+def apply_weights(W: jnp.ndarray, Y: jnp.ndarray) -> jnp.ndarray:
+    """X = W @ Y over Y's leading axis: W (mn, K), Y (K, ...) -> (mn, ...).
+
+    The decode contracts over the K workers; the encode uses it too, with
+    one row of coefficients over the source blocks.  Written as K unrolled
+    scaled adds, one elementwise fusion with no dot: the contraction is
+    only K long, so the dot gains nothing, and on the
+    TPU an f64 dot is emulated with temporaries many times the size of Y
+    (the paper's 8000^3 deployment would not fit one chip's HBM) while an
+    f32 dot at default precision takes a single bf16 pass.
+    """
+    w = W.reshape(W.shape + (1,) * (Y.ndim - 1))
+    X = w[:, 0] * Y[0].astype(W.dtype)
+    for k in range(1, Y.shape[0]):
+        X = X + w[:, k] * Y[k].astype(W.dtype)
+    return X
+
+
 def decode_with_weights(scheme: Scheme, W: jnp.ndarray, Y_all: jnp.ndarray,
                         s: float) -> jnp.ndarray:
     """Decode from a ready (mn, K) weight panel passed as an ARRAY.
@@ -162,9 +180,7 @@ def decode_with_weights(scheme: Scheme, W: jnp.ndarray, Y_all: jnp.ndarray,
     columns in W.  Because W is an operand (not a closed-over constant),
     one compiled executable serves every concrete erasure pattern.
     """
-    K = Y_all.shape[0]
-    Yf = Y_all.reshape(K, -1)
-    Xu = W @ Yf.astype(W.dtype)                              # (mn, E)
+    Xu = apply_weights(W, Y_all)                             # (mn, br, bt)
     return _finish_extract(scheme, Xu, s, Y_all.shape[1:])
 
 

@@ -27,17 +27,10 @@ __all__ = ["AxisRules", "axis_rules", "current_rules", "shard",
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions, replication checks off.
+    """``jax.shard_map`` with replication checks off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    jax >= 0.6 exposes ``jax.shard_map`` (check_vma kwarg); 0.4/0.5 ship it
-    in ``jax.experimental.shard_map`` with the older check_rep spelling.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
 
 AxisName = Union[str, None]
 

@@ -30,7 +30,8 @@ def _matmul_t_kernel(a_ref, b_ref, out_ref, acc_ref, *, k_steps: int):
 
     # a tile: (bk, bm) - already the transposed orientation; b tile: (bk, bn).
     acc_ref[...] += jnp.dot(
-        a_ref[...].T, b_ref[...], preferred_element_type=acc_ref.dtype)
+        a_ref[...].T, b_ref[...], preferred_element_type=acc_ref.dtype,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():

@@ -17,13 +17,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 __all__ = ["decode_pallas", "decode_partial_pallas"]
 
+# Block indices must be int32: under jax_enable_x64 a Python 0 becomes an
+# int64 constant, and Mosaic then fails to legalize the index map.
+_ZERO = np.int32(0)
+
 
 def _decode_kernel(w_ref, y_ref, out_ref, *, s: float, extract: bool):
-    X = jnp.dot(w_ref[...], y_ref[...], preferred_element_type=out_ref.dtype)
+    X = jnp.dot(w_ref[...], y_ref[...], preferred_element_type=out_ref.dtype,
+                precision=jax.lax.Precision.HIGHEST)
     R = jnp.round(X)
     if extract:
         C_hat = R - jnp.floor(R / s) * s          # mod s in [0, s)
@@ -58,17 +64,18 @@ def decode_pallas(
         kern,
         grid=(E // e_blk,),
         in_specs=[
-            pl.BlockSpec((mn, tau), lambda e: (0, 0)),     # resident panel
-            pl.BlockSpec((tau, e_blk), lambda e: (0, e)),  # streamed
+            pl.BlockSpec((mn, tau), lambda e: (_ZERO, _ZERO)),  # resident
+            pl.BlockSpec((tau, e_blk), lambda e: (_ZERO, e)),   # streamed
         ],
-        out_specs=pl.BlockSpec((mn, e_blk), lambda e: (0, e)),
+        out_specs=pl.BlockSpec((mn, e_blk), lambda e: (_ZERO, e)),
         out_shape=jax.ShapeDtypeStruct((mn, E), W.dtype),
         interpret=interpret,
     )(W, Y)
 
 
 def _decode_partial_kernel(w_ref, y_ref, out_ref, *, s: float, extract: bool):
-    X = jnp.dot(w_ref[0], y_ref[0], preferred_element_type=out_ref.dtype)
+    X = jnp.dot(w_ref[0], y_ref[0], preferred_element_type=out_ref.dtype,
+                precision=jax.lax.Precision.HIGHEST)
     R = jnp.round(X)
     if extract:
         C_hat = R - jnp.floor(R / s) * s          # mod s in [0, s)
@@ -107,10 +114,10 @@ def decode_partial_pallas(
         kern,
         grid=(Q, Ec // e_blk),
         in_specs=[
-            pl.BlockSpec((1, mn, K), lambda q, e: (q, 0, 0)),     # panel q
-            pl.BlockSpec((1, K, e_blk), lambda q, e: (q, 0, e)),  # streamed
+            pl.BlockSpec((1, mn, K), lambda q, e: (q, _ZERO, _ZERO)),  # panel
+            pl.BlockSpec((1, K, e_blk), lambda q, e: (q, _ZERO, e)),   # streamed
         ],
-        out_specs=pl.BlockSpec((1, mn, e_blk), lambda q, e: (q, 0, e)),
+        out_specs=pl.BlockSpec((1, mn, e_blk), lambda q, e: (q, _ZERO, e)),
         out_shape=jax.ShapeDtypeStruct((Q, mn, Ec), W_stack.dtype),
         interpret=interpret,
     )(W_stack, Y)

@@ -17,9 +17,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 __all__ = ["encode_pallas"]
+
+# Block indices must be int32: under jax_enable_x64 a Python 0 becomes an
+# int64 constant, and Mosaic then fails to legalize the index map.
+_ZERO = np.int32(0)
 
 
 def _encode_kernel(coeff_ref, blocks_ref, out_ref):
@@ -27,6 +32,7 @@ def _encode_kernel(coeff_ref, blocks_ref, out_ref):
     out_ref[...] = jnp.dot(
         coeff_ref[...], blocks_ref[...],
         preferred_element_type=out_ref.dtype,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -49,10 +55,10 @@ def encode_pallas(
         _encode_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((K, P), lambda e: (0, 0)),        # resident panel
-            pl.BlockSpec((P, e_blk), lambda e: (0, e)),    # streamed
+            pl.BlockSpec((K, P), lambda e: (_ZERO, _ZERO)),  # resident panel
+            pl.BlockSpec((P, e_blk), lambda e: (_ZERO, e)),  # streamed
         ],
-        out_specs=pl.BlockSpec((K, e_blk), lambda e: (0, e)),
+        out_specs=pl.BlockSpec((K, e_blk), lambda e: (_ZERO, e)),
         out_shape=jax.ShapeDtypeStruct((K, E), coeff.dtype),
         interpret=interpret,
     )(coeff, blocks)

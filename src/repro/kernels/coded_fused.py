@@ -15,8 +15,10 @@ the same pipeline stage.
 
 Grid: (K, r/bm, t/bn, v/bk) with the contraction axis innermost so the
 (bm, bn) accumulator stays resident across the k sweep (output revisiting).
-The (K, P)/(K, Q) coefficient tables live in SMEM; row k is prefetched per
-grid step and read as scalars.
+The whole (K, P)/(K, Q) coefficient tables live in SMEM (a few hundred
+bytes) and are read as scalars at row ``program_id(0)``: a (1, P) block
+would break Mosaic's rule that a block's last two dims divide by (8, 128)
+or equal the array's.
 
 VMEM budget per grid step (f32 words):
     P*bk*bm  (A block tiles)  +  Q*bk*bn  (B block tiles)
@@ -33,10 +35,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_worker_pallas"]
+
+# Block indices must be int32: under jax_enable_x64 a Python 0 becomes an
+# int64 constant, and Mosaic then fails to legalize the index map.
+_ZERO = np.int32(0)
 
 
 def _fused_kernel(ca_ref, cb_ref, a_ref, b_ref, out_ref, acc_ref, *,
@@ -49,18 +56,21 @@ def _fused_kernel(ca_ref, cb_ref, a_ref, b_ref, out_ref, acc_ref, *,
     # weighted sums of the P (resp. Q) source-block tiles.  P, Q are static
     # and small, so the loop unrolls into scalar-broadcast multiply-adds on
     # the VPU; coefficients are scalar reads from the SMEM row.
+    kw = pl.program_id(0)
     P = a_ref.shape[0]
     Q = b_ref.shape[0]
-    a_tilde = ca_ref[0, 0] * a_ref[0]
+    a_tilde = ca_ref[kw, 0] * a_ref[0]
     for pp in range(1, P):
-        a_tilde += ca_ref[0, pp] * a_ref[pp]
-    b_tilde = cb_ref[0, 0] * b_ref[0]
+        a_tilde += ca_ref[kw, pp] * a_ref[pp]
+    b_tilde = cb_ref[kw, 0] * b_ref[0]
     for qq in range(1, Q):
-        b_tilde += cb_ref[0, qq] * b_ref[qq]
+        b_tilde += cb_ref[kw, qq] * b_ref[qq]
 
-    # WORKER product on the MXU; accumulate across the v sweep.
+    # WORKER product on the MXU at full f32 precision (the default may take
+    # a single bf16 pass); accumulate across the v sweep.
     acc_ref[...] += jnp.dot(
-        a_tilde.T, b_tilde, preferred_element_type=acc_ref.dtype)
+        a_tilde.T, b_tilde, preferred_element_type=acc_ref.dtype,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(pl.program_id(3) == k_steps - 1)
     def _flush():
@@ -106,12 +116,12 @@ def fused_worker_pallas(
         kern,
         grid=(K, r // bm, t // bn, k_steps),
         in_specs=[
-            pl.BlockSpec((1, P), lambda kw, i, j, k: (kw, 0),
+            pl.BlockSpec((K, P), lambda kw, i, j, k: (_ZERO, _ZERO),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, Q), lambda kw, i, j, k: (kw, 0),
+            pl.BlockSpec((K, Q), lambda kw, i, j, k: (_ZERO, _ZERO),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((P, bk, bm), lambda kw, i, j, k: (0, k, i)),
-            pl.BlockSpec((Q, bk, bn), lambda kw, i, j, k: (0, k, j)),
+            pl.BlockSpec((P, bk, bm), lambda kw, i, j, k: (_ZERO, k, i)),
+            pl.BlockSpec((Q, bk, bn), lambda kw, i, j, k: (_ZERO, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda kw, i, j, k: (kw, i, j)),
         out_shape=jax.ShapeDtypeStruct((K, r, t), out_dtype),

@@ -3,6 +3,12 @@
 Pad-to-tile, backend dispatch (interpret=True off-TPU so the kernel bodies
 execute on CPU for tests/benches), and plan-level convenience entry points
 used by the distributed runtime.
+
+Two dtype rules hold at this seam.  Complex operands (unit-circle plans)
+have no Pallas TPU lowering and run the jnp oracle instead, counted as
+``kernel.fallback{op}`` under ``repro.obs``.  float64 has no Pallas TPU
+lowering either: compiling a kernel for the chip with f64 operands raises
+and names the XLA worker stage (interpret mode off the chip runs f64).
 """
 from __future__ import annotations
 
@@ -29,6 +35,17 @@ def on_tpu() -> bool:
 
 def _interpret() -> bool:
     return not on_tpu()
+
+
+def _require_kernel_dtype(op: str, *xs) -> None:
+    """Raise before a float64 kernel is compiled for the chip."""
+    if _interpret() or not any(x.dtype == jnp.float64 for x in xs):
+        return
+    raise TypeError(
+        f"ops.{op}: Pallas TPU kernels have no float64. For exact float64 "
+        "run the XLA worker stage (CodedMatmul backend='reference', or "
+        "MeshExecutor(mesh, use_kernels=False) on the mesh backend); for "
+        "the kernels use dtype=float32.")
 
 
 def _instrumented(op: str):
@@ -78,7 +95,9 @@ def encode(coeff: jnp.ndarray, blocks: jnp.ndarray, *, e_blk: int = 2048) -> jnp
     """coeff: (K, P), blocks: (P, E) -> (K, E) coded blocks (flattened)."""
     if jnp.iscomplexobj(coeff):
         # Pallas TPU has no complex support; unit-circle plans use the oracle.
+        obs.count("kernel.fallback", op="encode")
         return ref.encode_ref(coeff, blocks)
+    _require_kernel_dtype("encode", coeff, blocks)
     E = blocks.shape[-1]
     e_blk = _pow2_tile(e_blk, E)
     bp = _pad_last(blocks, e_blk)
@@ -91,7 +110,9 @@ def decode(W: jnp.ndarray, Y: jnp.ndarray, s: float, *, extract: bool = True,
            e_blk: int = 2048) -> jnp.ndarray:
     """W: (mn, tau), Y: (tau, E) -> (mn, E) decoded + digit-extracted."""
     if jnp.iscomplexobj(W) or jnp.iscomplexobj(Y):
+        obs.count("kernel.fallback", op="decode")
         return ref.decode_ref(W, Y, s)
+    _require_kernel_dtype("decode", W, Y)
     E = Y.shape[-1]
     e_blk = _pow2_tile(e_blk, E)
     Yp = _pad_last(Y, e_blk)
@@ -107,11 +128,13 @@ def decode_partial(W_stack: jnp.ndarray, Y: jnp.ndarray, s: float, *,
 
     The partial-straggler decode stage: chunk q's worker outputs hit chunk
     q's panel, with digit extraction fused.  Complex panels (unit-circle
-    plans) fall back to the per-chunk jnp oracle.
+    plans) fall back to the per-chunk jnp oracle (counted).
     """
     if jnp.iscomplexobj(W_stack) or jnp.iscomplexobj(Y):
+        obs.count("kernel.fallback", op="decode_partial")
         return jnp.stack([ref.decode_ref(W_stack[q], Y[q], s)
                           for q in range(W_stack.shape[0])])
+    _require_kernel_dtype("decode_partial", W_stack, Y)
     Ec = Y.shape[-1]
     e_blk = _pow2_tile(e_blk, Ec)
     Yp = _pad_last(Y, e_blk)
@@ -137,11 +160,13 @@ def fused_worker(
 
     Pads v/r/t to tile multiples; promotes blocks to the coefficient dtype
     (encode semantics).  Complex plans (unit-circle points) fall back to the
-    jnp oracle - Pallas TPU has no complex support.
+    jnp oracle (counted) - Pallas TPU has no complex support.
     """
     if any(jnp.iscomplexobj(x) for x in (coeff_a, coeff_b, a_blocks, b_blocks)):
+        obs.count("kernel.fallback", op="fused_worker")
         return ref.fused_worker_ref(coeff_a, coeff_b, a_blocks, b_blocks,
                                     out_dtype)
+    _require_kernel_dtype("fused_worker", coeff_a, coeff_b, a_blocks, b_blocks)
     dt = jnp.result_type(coeff_a.dtype, coeff_b.dtype,
                          a_blocks.dtype, b_blocks.dtype)
     ca = coeff_a.astype(dt)
@@ -169,7 +194,9 @@ def matmul_t(A: jnp.ndarray, B: jnp.ndarray, *, bm: int = 128, bn: int = 128,
              bk: int = 512, out_dtype=None) -> jnp.ndarray:
     """A: (v, r), B: (v, t) -> A^T B with MXU tiling; pads to tile multiples."""
     if jnp.iscomplexobj(A) or jnp.iscomplexobj(B):
+        obs.count("kernel.fallback", op="matmul_t")
         return ref.matmul_t_ref(A, B, out_dtype)
+    _require_kernel_dtype("matmul_t", A, B)
     v, r = A.shape
     _, t = B.shape
     bm_ = _pow2_tile(bm, r)

@@ -5,7 +5,15 @@ This is the launch-layer face of the ROADMAP serving story: a resident
 facade absorbs worker loss as DATA (no recompiles, no restarts) while the
 executable memo keeps per-request latency at the warm-call floor.  Single
 host by default; ``--backend mesh`` runs one worker per device (spawn with
-XLA_FLAGS=--xla_force_host_platform_device_count=8 off-TPU).
+XLA_FLAGS=--xla_force_host_platform_device_count=8 off-TPU).  Every request
+is checked against the plain ``A^T B``; a mismatch raises.
+
+The serving paths compute in float64, the only dtype in which these
+deployments decode exactly.  Pallas TPU kernels have no float64, so on a
+TPU serve with ``--backend reference`` (XLA emulates f64) or, on the mesh,
+``--backend mesh --no-kernels``; the kernel backends raise there and say
+so.  ``main`` turns on JAX's persistent compilation cache
+(``repro.launch.compile_cache``).
 
 ``--adaptive`` swaps the single fixed plan for the control plane
 (``repro.control``): a ``PlanLadder`` over the paper's bec <-> tradeoff <->
@@ -65,6 +73,8 @@ on the timeline).  Render a terminal summary with
 Usage:
   PYTHONPATH=src python -m repro.launch.coded_serve --backend fused \
       --requests 12 --size 256 --fail-rate 0.3
+  python -m repro.launch.coded_serve --backend reference --size 8000 \
+      --requests 3                                   # one TPU chip, f64
   PYTHONPATH=src python -m repro.launch.coded_serve --adaptive \
       --requests 16 --size 64 --fail-rate 0.25 --batch 8 \
       --slo-quantile 0.99 --slo-ms 1800
@@ -83,6 +93,7 @@ import time
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 
 def _oracle(A, B) -> np.ndarray:
@@ -92,10 +103,33 @@ def _oracle(A, B) -> np.ndarray:
     return np.asarray(uncoded_matmul(A, B))
 
 
+def _worker_mesh(K: int):
+    """(data, model) mesh over every device, one coded worker per model slot."""
+    n_dev = len(jax.devices())
+    if n_dev % K:
+        raise SystemExit(
+            f"--backend mesh needs a multiple of K={K} devices, have {n_dev}")
+    return jax.make_mesh((n_dev // K, K), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+
+
+def _backend(args, K: int):
+    """The executor ``--backend``/``--no-kernels`` name (mesh built here)."""
+    if args.backend != "mesh":
+        return args.backend
+    from repro.runtime import MeshExecutor
+
+    return MeshExecutor(_worker_mesh(K), use_kernels=not args.no_kernels)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="fused",
                     choices=["reference", "staged", "fused", "mesh"])
+    ap.add_argument("--no-kernels", action="store_true",
+                    help="mesh backend: compute each device's worker product "
+                         "with XLA instead of the Pallas kernels (needed "
+                         "for float64 on a TPU)")
     ap.add_argument("--adaptive", action="store_true",
                     help="serve through the control plane (PlanLadder + "
                          "monitor + expected-latency policy)")
@@ -169,6 +203,9 @@ def main(argv=None):
                          "(bit-deterministic against the recording)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if args.no_kernels and args.backend != "mesh":
+        ap.error("--no-kernels applies to --backend mesh; the local XLA "
+                 "worker stage is --backend reference")
     if args.feedback and args.slo_ms is None:
         ap.error("--feedback requires --slo-ms (the bound realized "
                  "latencies are judged by)")
@@ -187,6 +224,9 @@ def main(argv=None):
             ap.error("--serve-tier takes SLOs and feedback from the tenant "
                      "spec, not --slo-ms/--feedback, and does not replay "
                      "adaptive traces")
+        if args.backend == "mesh":
+            ap.error("--serve-tier needs a local backend: its split "
+                     "worker/decode stages run fused on mesh")
         return _with_obs(run_serve_tier, args)
     if args.tenant_spec or args.no_pipeline or args.max_batch:
         ap.error("--tenant-spec/--no-pipeline/--max-batch need --serve-tier")
@@ -201,6 +241,9 @@ def main(argv=None):
         if args.scenario not in (None, "pool_resize"):
             ap.error("--elastic is driven by the pool_resize scenario; drop "
                      f"--scenario {args.scenario}")
+        if args.backend == "mesh":
+            ap.error("--elastic does not drive the mesh backend yet; use a "
+                     "local backend")
         return _with_obs(run_elastic, args)
     if args.adaptive:
         return _with_obs(run_adaptive, args)
@@ -213,7 +256,8 @@ def main(argv=None):
 
 
 def _with_obs(runner, args):
-    """Run ``runner`` with observability on when an export flag asks.
+    """Run ``runner`` with the compile cache on, and observability on when
+    an export flag asks.
 
     ``--metrics-out``/``--perfetto-out`` enable a FRESH obs session (so
     the dumps cover exactly this run), then write the Prometheus text
@@ -221,6 +265,9 @@ def _with_obs(runner, args):
     flag the runner executes with observability untouched (off unless
     REPRO_OBS enabled it), keeping the default path zero-overhead.
     """
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if not (args.metrics_out or args.perfetto_out):
         return runner(args)
     from repro import obs
@@ -238,6 +285,11 @@ def _with_obs(runner, args):
 
 
 def run_static(args):
+    """One fixed bec plan (K=4) served request by request.
+
+    Returns the per-request latencies (ms) and the decoded products (host
+    arrays), each already checked against the plain ``A^T B``.
+    """
     from repro.core import make_plan
     from repro.core.numerics import enable_x64
     from repro.runtime import CodedMatmul
@@ -249,15 +301,7 @@ def run_static(args):
         v, r, t = args.size, args.size // 2, args.size // 2
         plan = make_plan("bec", 2, 2, 1, K=4, L=v * 4 * 4 + 1,
                          points="chebyshev")
-        mesh = None
-        if args.backend == "mesh":
-            n_dev = len(jax.devices())
-            if n_dev % plan.K:
-                raise SystemExit(
-                    f"--backend mesh needs a multiple of K={plan.K} devices, "
-                    f"have {n_dev}")
-            mesh = jax.make_mesh((n_dev // plan.K, plan.K), ("data", "model"))
-        cm = CodedMatmul(plan, args.backend, mesh=mesh, dtype=jnp.float64)
+        cm = CodedMatmul(plan, _backend(args, plan.K), dtype=jnp.float64)
 
         def request():
             shape = (args.batch,) if args.batch else ()
@@ -272,7 +316,7 @@ def run_static(args):
 
         print(f"backend={args.backend} K={plan.K} tau={plan.tau} "
               f"v={v} r={r} t={t} batch={args.batch or 'none'}")
-        lat = []
+        lat, outputs = [], []
         for i in range(args.requests):
             A, B, erased = request()
             t0 = time.perf_counter()
@@ -280,15 +324,18 @@ def run_static(args):
             jax.block_until_ready(C)
             ms = (time.perf_counter() - t0) * 1e3
             lat.append(ms)
-            exact = bool(np.array_equal(np.asarray(C), _oracle(A, B)))
+            outputs.append(np.asarray(C))
+            exact = bool(np.array_equal(outputs[-1], _oracle(A, B)))
             print(f"req {i:02d}: erased={str(erased) if erased else '[]':<8} "
                   f"{ms:8.1f} ms  {'exact' if exact else 'CHECK FAILED'}")
+            if not exact:
+                raise RuntimeError(f"request {i}: coded C != A^T B")
         info = cm.cache_info()
         print(f"cold {lat[0]:.1f} ms -> warm p50 {np.median(lat[1:]):.1f} ms; "
               f"{info['builds']} executable(s), {info['hits']} cache hits, "
               f"{info['panel_builds']} decode panels, "
               f"{cm.executable_cache_size()} jit specialisations")
-        return lat
+        return lat, outputs
 
 
 def run_adaptive(args):
@@ -305,18 +352,8 @@ def run_adaptive(args):
         v = max(args.size - args.size % p, p)
         r, t = (v // 2) - (v // 2) % m, (v // 2) - (v // 2) % n
         backend = args.backend
-        mesh = None
-        if backend == "mesh":
-            import jax
-
-            n_dev = len(jax.devices())
-            if n_dev % K:
-                raise SystemExit(
-                    f"--backend mesh needs a multiple of K={K} devices, "
-                    f"have {n_dev}")
-            mesh = jax.make_mesh((n_dev // K, K), ("data", "model"))
         ladder = PlanLadder(p, m, n, K=K, L=conservative_L(v, 4, 4),
-                            backend=backend, mesh=mesh)
+                            backend=_backend(args, K))
         # batched requests vary in size: prewarm power-of-two buckets so
         # round-up padding keeps every size recompile-free.
         buckets = ()
@@ -458,6 +495,8 @@ def run_adaptive(args):
                   f"sim {rep.sim_latency_s:6.3f} s  wall {rep.wall_ms:7.1f} ms"
                   f"{tail}{q_eff}{partial}{thr_eff}  slack={rep.slack}  "
                   f"{'exact' if rep.exact else 'CHECK FAILED'}{flag}")
+            if not rep.exact:
+                raise RuntimeError(f"request {rep.step}: coded C != A^T B")
         info = ladder.cache_info()
         assert info["builds"] == builds_at_prewarm, (
             f"recompile after prewarm: {info}")
@@ -506,13 +545,8 @@ def run_elastic(args):
         p, m, n = 3, 2, 1
         v = max(args.size - args.size % p, p)
         r, t = (v // 2) - (v // 2) % m, v // 2
-        backend = args.backend
-        if backend == "mesh":
-            print("--elastic does not drive the mesh backend yet; "
-                  "falling back to the reference executor")
-            backend = "reference"
         ladder = PlanLadder(p, m, n, K=len(pool), L=conservative_L(v, 4, 4),
-                            backend=backend, include=["polycode"])
+                            backend=args.backend, include=["polycode"])
         info = ladder.prewarm((v, r), (v, t))
         builds_marker = info["builds"]
         print(f"elastic universe={universe} pool={pool} "
@@ -572,6 +606,8 @@ def run_elastic(args):
                   f"wall {rep.wall_ms:7.1f} ms  slack={rep.slack}  "
                   f"{'exact' if rep.exact else 'CHECK FAILED'}"
                   f"{' RESPECIALIZED' if rep.respecialize else ''}")
+            if not rep.exact:
+                raise RuntimeError(f"request {rep.step}: coded C != A^T B")
         info = ladder.cache_info()
         assert info["builds"] == builds_marker, (
             f"recompile outside a pool transition: {info}")
@@ -607,14 +643,8 @@ def run_serve_tier(args):
         p, m, n, K = 4, 2, 1, 12
         v = max(args.size - args.size % p, p)
         r, t = (v // 2) - (v // 2) % m, (v // 2) - (v // 2) % n
-        backend = args.backend
-        if backend == "mesh":
-            print("--serve-tier does not drive the mesh backend (the split "
-                  "worker/decode stages run fused on mesh); falling back to "
-                  "the reference executor")
-            backend = "reference"
         ladder = PlanLadder(p, m, n, K=K, L=conservative_L(v, 4, 4),
-                            backend=backend)
+                            backend=args.backend)
         top = args.max_batch or 8
         buckets = tuple(1 << i for i in range((top - 1).bit_length() + 1))
         split = args.sub_tasks == 1

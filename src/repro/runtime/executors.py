@@ -41,11 +41,16 @@ from jax.sharding import PartitionSpec as P
 from repro.core.api import (
     CodedMatmulPlan,
     _coeff_dtype,
-    encode_blocks,
     fused_worker_products,
     worker_products,
 )
-from repro.core.decoding import decode_masked, decode_with_weights, digit_extract
+from repro.core.decoding import (
+    apply_weights,
+    decode_masked,
+    decode_with_weights,
+    digit_extract,
+)
+from repro.core.numerics import precise_matmul_t
 from repro.core.partition import block_decompose, block_recompose, unpad
 from repro.runtime.partial import chunk_bounds
 from repro.distributed.sharding import shard_map_compat
@@ -242,8 +247,7 @@ class ReferenceExecutor(LocalExecutor):
 
     def worker_products(self, plan, a_blocks, b_blocks):
         """Encode + per-worker products as plain einsums (the oracle path)."""
-        a_tilde, b_tilde = encode_blocks(plan, a_blocks, b_blocks)
-        return worker_products(a_tilde, b_tilde)
+        return worker_products(plan, a_blocks, b_blocks)
 
 
 class StagedKernelExecutor(LocalExecutor):
@@ -321,9 +325,11 @@ def _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
         b_tilde = kops.encode(cb.reshape(1, p * n),
                               b_blocks.reshape(p * n, bv * bt)).reshape(bv, bt)
         return kops.matmul_t(a_tilde, b_tilde)                # (br, bt)
-    a_tilde = jnp.einsum("pm,pmvr->vr", ca[0], a_blocks)
-    b_tilde = jnp.einsum("pn,pnvt->vt", cb[0], b_blocks)
-    return a_tilde.T @ b_tilde
+    a_tilde = apply_weights(ca.reshape(1, p * m),
+                            a_blocks.reshape(p * m, bv, br))[0]
+    b_tilde = apply_weights(cb.reshape(1, p * n),
+                            b_blocks.reshape(p * n, bv, bt))[0]
+    return precise_matmul_t(a_tilde, b_tilde)
 
 
 def _mesh_worker_body(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW,
@@ -348,7 +354,7 @@ def _mesh_worker_body(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW,
         W = zW                                               # (mn, K), ready
     else:
         W = _decode_weights_masked(zW, mask, tau, useful)    # (mn, K)
-    X = jnp.einsum("uk,krt->urt", W, Y)
+    X = apply_weights(W, Y)
     C = digit_extract(X, s) if s is not None else jnp.round(X)
     return C.reshape(m, n, br, bt)
 
@@ -393,7 +399,7 @@ def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
             W_c = _decode_weights_masked(zW, mask_c, tau, useful)
         Yc = Y[:, bounds[c]:bounds[c + 1], :]
         Yc = Yc * mask_c.astype(Yc.dtype)[:, None, None]
-        parts.append(jnp.einsum("uk,krt->urt", W_c, Yc))
+        parts.append(apply_weights(W_c, Yc))
     X = jnp.concatenate(parts, axis=1)                       # (mn, br, bt)
     C = digit_extract(X, s) if s is not None else jnp.round(X)
     return C.reshape(m, n, br, bt)

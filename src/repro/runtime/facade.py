@@ -137,6 +137,12 @@ class CodedMatmul:
     Backends: "reference" | "staged" | "fused" (default) | "mesh" (pass
     ``mesh=``, one worker per device along ``axis``).  All backends are
     bit-identical for integer inputs within the plan's bounds.
+
+    On a TPU the kernel backends ("staged", "fused", "mesh" with
+    ``use_kernels=True``) take float32 only: Pallas TPU has no float64,
+    and a float64 call there raises naming the way out.  Exact float64
+    runs on the XLA worker stage: "reference", or "mesh" with
+    ``use_kernels=False``.
     """
 
     def __init__(self, plan: CodedMatmulPlan, backend="fused", *,

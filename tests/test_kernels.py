@@ -232,3 +232,91 @@ class TestKernelPipelineEndToEnd:
         C = unpad(C, (r, t))
         np.testing.assert_array_equal(np.asarray(C),
                                       np.asarray(uncoded_matmul(A, B)))
+
+
+class TestKernelDtypeRules:
+    """Complex operands run the oracle, counted; float64 never compiles
+    for the chip (Pallas TPU has no f64) and the error names the way out."""
+
+    def test_complex_fallback_is_counted(self, rng):
+        from repro import obs
+
+        c = jnp.asarray(rng.normal(size=(4, 4)) + 1j * rng.normal(size=(4, 4)))
+        blocks = jnp.asarray(rng.normal(size=(4, 64)), jnp.float32)
+        obs.enable(fresh=True)
+        try:
+            ops.encode(c, blocks)
+            ops.matmul_t(c, c)
+            reg = obs.session().registry
+            assert reg.value("kernel.fallback", op="encode") == 1
+            assert reg.value("kernel.fallback", op="matmul_t") == 1
+            assert reg.total("kernel.fallback") == 2
+            ops.encode(c.real.astype(jnp.float32), blocks)  # a kernel call
+            assert reg.total("kernel.fallback") == 2
+        finally:
+            obs.disable()
+
+    @pytest.mark.parametrize("op", ["encode", "decode", "decode_partial",
+                                    "fused_worker", "matmul_t"])
+    def test_float64_kernel_for_the_chip_raises(self, monkeypatch, op):
+        from repro.core.numerics import enable_x64
+
+        monkeypatch.setattr(ops, "_interpret", lambda: False)
+        with enable_x64():
+            x = jnp.ones((4, 8), jnp.float64)
+            args = {"encode": (x, x.T),
+                    "decode": (x, x.T, 64.0),
+                    "decode_partial": (x[None], x.T[None], 64.0),
+                    "fused_worker": (x, x, x.reshape(8, 2, 2),
+                                     x.reshape(8, 2, 2)),
+                    "matmul_t": (x, x)}[op]
+            with pytest.raises(TypeError, match="backend='reference'"):
+                getattr(ops, op)(*args)
+
+
+class TestFloat64ProductOnTheChip:
+    """The float64 worker product a TPU takes (int8 slices, exact int32
+    sums), run here on the CPU against NumPy and the plain einsum path."""
+
+    @pytest.mark.parametrize("data", ["int20", "normal", "wide-scales"])
+    def test_sliced_matches_numpy(self, rng, data):
+        from repro.core.numerics import enable_x64, sliced_matmul_t
+
+        n = 4000
+        if data == "int20":
+            a = rng.integers(0, 2 ** 20, (n, 24)).astype(np.float64)
+            b = rng.integers(-2 ** 20, 2 ** 20, (n, 16)).astype(np.float64)
+        else:
+            a = rng.standard_normal((n, 24))
+            b = rng.standard_normal((n, 16))
+            if data == "wide-scales":
+                a *= np.exp2(rng.integers(-60, 60, (1, 24)))
+                a[:, 0] = 0.0
+        exp = a.T @ b
+        with enable_x64():
+            got = np.asarray(jax.jit(sliced_matmul_t)(jnp.asarray(a),
+                                                      jnp.asarray(b)))
+        if data == "int20":                  # every partial sum is exact
+            np.testing.assert_array_equal(got, exp)
+        else:                                # as close as an f64 dot gets
+            err = np.abs(got - exp) / np.max(np.abs(exp), axis=1,
+                                              keepdims=True).clip(1e-300)
+            assert np.max(err) < 1e-14, np.max(err)
+
+    @pytest.mark.parametrize("kind,erased", [("bec", [1, 2, 4, 5, 7, 8]),
+                                             ("polycode", [4])])
+    def test_reference_executor_exact_on_the_chip_path(self, monkeypatch,
+                                                       kind, erased):
+        from repro.configs.paper_matmul import SMOKE as g
+        from repro.core import make_plan, numerics
+        from repro.runtime import CodedMatmul
+
+        rng = np.random.default_rng(1)
+        A = rng.integers(0, g.entry_max + 1, (g.v, g.r)).astype(np.float64)
+        B = rng.integers(0, g.entry_max + 1, (g.v, g.t)).astype(np.float64)
+        plan = make_plan(kind, g.p, g.m, g.n, K=g.K, L=g.L, points=g.points)
+        monkeypatch.setattr(numerics, "_emulated_f64", lambda: True)
+        with numerics.enable_x64():
+            cm = CodedMatmul(plan, "reference", dtype=jnp.float64)
+            C = np.asarray(cm(jnp.asarray(A), jnp.asarray(B), erased=erased))
+        np.testing.assert_array_equal(C, A.T @ B)

@@ -191,8 +191,9 @@ class TestMoEParallel:
         out = run_child("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.sharding import axis_rules, default_rules
+from repro.launch.mesh import make_debug_mesh
 from repro.models.moe import MoEConfig, init_moe, apply_moe, _moe_dense
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 rules = default_rules(mesh)
 cfg = MoEConfig(n_experts=8, top_k=2, d_expert_ff=32, n_shared=1,
                 capacity_factor=64.0)  # no drops
@@ -213,8 +214,9 @@ print("OK", rel)
         out = run_child("""
 import jax, jax.numpy as jnp
 from repro.distributed.sharding import axis_rules, default_rules
+from repro.launch.mesh import make_debug_mesh
 from repro.models.moe import MoEConfig, init_moe, apply_moe
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 rules = default_rules(mesh)
 cfg = MoEConfig(n_experts=8, top_k=2, d_expert_ff=32, capacity_factor=0.1)
 params = init_moe(jax.random.PRNGKey(0), 16, cfg, ep_size=4, dtype=jnp.float32)
@@ -248,7 +250,8 @@ ocfg = OptConfig()
 # single device
 p1, o1, m1 = jax.jit(make_train_step(cfg, ocfg, None))(params, opt, batch)
 # mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 4)
 rules = default_rules(mesh)
 p2, o2, m2 = jax.jit(make_train_step(cfg, ocfg, rules))(params, opt, batch)
 l1, l2 = float(m1["loss"]), float(m2["loss"])
