@@ -33,9 +33,17 @@ __all__ = [
 
 
 def digit_extract(X: jnp.ndarray, s: float, round_first: bool = True) -> jnp.ndarray:
-    """Recover the s^0 digit of X = ... + *s^{-1} + C + *s + ... , |C| < s/2."""
+    """Recover the s^0 digit of X = ... + *s^{-1} + C + *s + ... , |C| < s/2.
+
+    C is round(X) mod s taken in (-s/2, s/2].  The residue is
+    R - floor(R / s) * s, the arithmetic of the Pallas decode kernel, so
+    the XLA and kernel decodes extract alike.  In IEEE float64 every step
+    is exact for an integer |R| < 2^53 and an integer s; on a TPU's
+    emulated float64 it agrees with ``jnp.mod`` wherever R is held exactly
+    (``scripts/f64_diag.py``).
+    """
     R = jnp.round(X) if round_first else X
-    C_hat = jnp.mod(R, s)  # convention: result in [0, s)
+    C_hat = R - jnp.floor(R / s) * s             # R mod s, in [0, s)
     return jnp.where(C_hat <= s / 2, C_hat, C_hat - s)
 
 

@@ -10,9 +10,10 @@ is checked against the plain ``A^T B``; a mismatch raises.
 
 The serving paths compute in float64, the only dtype in which these
 deployments decode exactly.  Pallas TPU kernels have no float64, so on a
-TPU serve with ``--backend reference`` (XLA emulates f64) or, on the mesh,
-``--backend mesh --no-kernels``; the kernel backends raise there and say
-so.  ``main`` turns on JAX's persistent compilation cache
+TPU serve with ``--backend reference`` (XLA emulates f64) or ``--backend
+mesh``, whose devices then take their worker products with XLA (the banner
+says which); ``fused`` and ``staged`` raise there and say so.  ``main``
+turns on JAX's persistent compilation cache
 (``repro.launch.compile_cache``).
 
 ``--adaptive`` swaps the single fixed plan for the control plane
@@ -114,22 +115,30 @@ def _worker_mesh(K: int):
 
 
 def _backend(args, K: int):
-    """The executor ``--backend``/``--no-kernels`` name (mesh built here)."""
+    """The executor ``--backend`` names (the mesh is built here).
+
+    Serving is float64, which Pallas has only off the TPU (interpret
+    mode): on a TPU the mesh takes each worker product with XLA.
+    """
     if args.backend != "mesh":
         return args.backend
+    from repro.kernels.ops import on_tpu
     from repro.runtime import MeshExecutor
 
-    return MeshExecutor(_worker_mesh(K), use_kernels=not args.no_kernels)
+    return MeshExecutor(_worker_mesh(K), use_kernels=not on_tpu())
+
+
+def _describe(backend) -> str:
+    """Banner name of a ``_backend`` result: the mesh says how it computes."""
+    if isinstance(backend, str):
+        return backend
+    return f"mesh[{'kernels' if backend.use_kernels else 'xla'}]"
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="fused",
                     choices=["reference", "staged", "fused", "mesh"])
-    ap.add_argument("--no-kernels", action="store_true",
-                    help="mesh backend: compute each device's worker product "
-                         "with XLA instead of the Pallas kernels (needed "
-                         "for float64 on a TPU)")
     ap.add_argument("--adaptive", action="store_true",
                     help="serve through the control plane (PlanLadder + "
                          "monitor + expected-latency policy)")
@@ -203,9 +212,6 @@ def main(argv=None):
                          "(bit-deterministic against the recording)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    if args.no_kernels and args.backend != "mesh":
-        ap.error("--no-kernels applies to --backend mesh; the local XLA "
-                 "worker stage is --backend reference")
     if args.feedback and args.slo_ms is None:
         ap.error("--feedback requires --slo-ms (the bound realized "
                  "latencies are judged by)")
@@ -301,7 +307,8 @@ def run_static(args):
         v, r, t = args.size, args.size // 2, args.size // 2
         plan = make_plan("bec", 2, 2, 1, K=4, L=v * 4 * 4 + 1,
                          points="chebyshev")
-        cm = CodedMatmul(plan, _backend(args, plan.K), dtype=jnp.float64)
+        backend = _backend(args, plan.K)
+        cm = CodedMatmul(plan, backend, dtype=jnp.float64)
 
         def request():
             shape = (args.batch,) if args.batch else ()
@@ -314,7 +321,7 @@ def run_static(args):
                             if rng.random() < args.fail_rate)
             return A, B, erased
 
-        print(f"backend={args.backend} K={plan.K} tau={plan.tau} "
+        print(f"backend={_describe(backend)} K={plan.K} tau={plan.tau} "
               f"v={v} r={r} t={t} batch={args.batch or 'none'}")
         lat, outputs = [], []
         for i in range(args.requests):
@@ -352,8 +359,9 @@ def run_adaptive(args):
         v = max(args.size - args.size % p, p)
         r, t = (v // 2) - (v // 2) % m, (v // 2) - (v // 2) % n
         backend = args.backend
+        executor = _backend(args, K)
         ladder = PlanLadder(p, m, n, K=K, L=conservative_L(v, 4, 4),
-                            backend=_backend(args, K))
+                            backend=executor)
         # batched requests vary in size: prewarm power-of-two buckets so
         # round-up padding keeps every size recompile-free.
         buckets = ()
@@ -363,7 +371,8 @@ def run_adaptive(args):
         info = ladder.prewarm((v, r), (v, t), batch_sizes=buckets,
                               sub_tasks=args.sub_tasks)
         builds_at_prewarm = info["builds"]
-        print(f"adaptive ladder rungs={ladder.rungs} "
+        print(f"adaptive backend={_describe(executor)} "
+              f"ladder rungs={ladder.rungs} "
               f"taus={[ladder.tau(x) for x in ladder.rungs]} K={K} "
               f"v={v} r={r} t={t} buckets={buckets or 'none'} "
               f"sub_tasks={args.sub_tasks}; "

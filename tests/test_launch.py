@@ -7,6 +7,7 @@ persistent compilation cache lives at one fixed place.
 """
 from __future__ import annotations
 
+import argparse
 from pathlib import Path
 
 import numpy as np
@@ -39,14 +40,24 @@ def test_failed_check_raises(no_cache, monkeypatch):
 
 
 @pytest.mark.parametrize("argv", [
-    ["--no-kernels"],
     ["--backend", "mesh", "--adaptive", "--elastic"],
     ["--backend", "mesh", "--serve-tier"],
-], ids=["no-kernels-off-mesh", "elastic-on-mesh", "serve-tier-on-mesh"])
+], ids=["elastic-on-mesh", "serve-tier-on-mesh"])
 def test_unservable_modes_are_argument_errors(no_cache, argv):
     with pytest.raises(SystemExit) as exc:
         coded_serve.main(argv)
     assert exc.value.code == 2
+
+
+@pytest.mark.parametrize("tpu", [True, False], ids=["tpu", "off-tpu"])
+def test_mesh_serves_float64_on_xla_where_pallas_lacks_it(monkeypatch, tpu):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    backend = coded_serve._backend(argparse.Namespace(backend="mesh"), K=1)
+    assert backend.use_kernels is not tpu
+    assert coded_serve._describe(backend) == ("mesh[xla]" if tpu
+                                              else "mesh[kernels]")
 
 
 class TestCompileCache:

@@ -144,3 +144,16 @@ class TestDigitExtraction:
         s = float(1 << 30)
         X = jnp.asarray([(1 << 29) - 1 + (1 << 30) * 7.0])
         assert float(digit_extract(X, s)[0]) == (1 << 29) - 1
+
+    @pytest.mark.parametrize("s", [1 << 26, 3 * (1 << 20) + 1, 40000002],
+                             ids=["pow2", "odd", "even"])
+    def test_matches_integer_residue_at_large_magnitude(self, rng, s):
+        # near multiples of s with |R| up to 2^52, plus the +-s/2 edges
+        k = rng.integers(-(1 << 52) // s, (1 << 52) // s, size=512)
+        edges = np.array([0, 1, -1, s // 2, s // 2 + 1, -(s // 2),
+                          -(s // 2) - 1, s - 1])
+        R = np.concatenate([k * s + rng.integers(-3, 4, size=512),
+                            k[:edges.size] * s + edges])
+        want = [c if c <= s / 2 else c - s for c in (int(x) % s for x in R)]
+        out = digit_extract(jnp.asarray(R.astype(np.float64)), float(s))
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want, float))
