@@ -1,0 +1,10 @@
+"""decode.apply_ms: device time per call of the ops scoped coded.decode."""
+
+from bench import stages
+
+
+def read(ctx):
+    """Self time per call of the traced calls' erasure and decode weighted
+    sums, on chip 0, or on the first chip whose record names the program's
+    ops (bench.stages); nothing without the scopes."""
+    return stages.stage_ms(ctx, "coded.decode")

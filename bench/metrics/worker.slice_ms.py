@@ -1,0 +1,10 @@
+"""worker.slice_ms: device time per call of the ops scoped coded.slice."""
+
+from bench import stages
+
+
+def read(ctx):
+    """Self time per call of the traced calls' Ozaki split of the worker
+    operands into int8 slices, on chip 0, or on the first chip whose record
+    names the program's ops (bench.stages); nothing without the scopes."""
+    return stages.stage_ms(ctx, "coded.slice")

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bounds as bounds_mod
 from repro.core.decoding import DecodePanelCache, apply_weights
 from repro.core.numerics import precise_matmul_t
@@ -187,7 +188,7 @@ def worker_products(plan: CodedMatmulPlan, a_blocks: jnp.ndarray,
     the f64 pipeline at the paper's 8000^3 inside one TPU chip's HBM.  The
     product is ``numerics.precise_matmul_t``: on a TPU, where XLA's
     emulated f64 dot falls short of f64, it is a sum of exact int8 slice
-    products.
+    products.  The encode is scoped ``coded.encode``.
     """
     p, m, bv, br = a_blocks.shape
     _, n, _, bt = b_blocks.shape
@@ -195,13 +196,16 @@ def worker_products(plan: CodedMatmulPlan, a_blocks: jnp.ndarray,
                      dtype=_coeff_dtype(a_blocks, plan))
     cb = jnp.asarray(plan.coeff_b.reshape(plan.K, 1, p * n),
                      dtype=_coeff_dtype(b_blocks, plan))
-    a_flat = a_blocks.reshape(p * m, bv, br)
-    b_flat = b_blocks.reshape(p * n, bv, bt)
+    with obs.stage(obs.ENCODE):
+        a_flat = a_blocks.reshape(p * m, bv, br)
+        b_flat = b_blocks.reshape(p * n, bv, bt)
 
     def one_worker(coeffs):
         ca_k, cb_k = coeffs                       # (1, p*m), (1, p*n)
-        return precise_matmul_t(apply_weights(ca_k, a_flat)[0],
-                                apply_weights(cb_k, b_flat)[0])
+        with obs.stage(obs.ENCODE):
+            a_k = apply_weights(ca_k, a_flat)[0]
+            b_k = apply_weights(cb_k, b_flat)[0]
+        return precise_matmul_t(a_k, b_k)
 
     return jax.lax.map(one_worker, (ca, cb))
 
@@ -222,9 +226,10 @@ def fused_worker_products(plan: CodedMatmulPlan, a_blocks: jnp.ndarray,
                      dtype=_coeff_dtype(a_blocks, plan))
     cb = jnp.asarray(plan.coeff_b.reshape(plan.K, p * n),
                      dtype=_coeff_dtype(b_blocks, plan))
-    return kops.fused_worker(ca, cb,
-                             a_blocks.reshape(p * m, bv, br),
-                             b_blocks.reshape(p * n, bv, bt))
+    with obs.stage(obs.DOTS):
+        return kops.fused_worker(ca, cb,
+                                 a_blocks.reshape(p * m, bv, br),
+                                 b_blocks.reshape(p * n, bv, bt))
 
 
 def _coeff_dtype(x: jnp.ndarray, plan: CodedMatmulPlan):

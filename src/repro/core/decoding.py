@@ -50,21 +50,25 @@ def digit_extract(X: jnp.ndarray, s: float, round_first: bool = True) -> jnp.nda
 def _finish_extract(scheme: Scheme, Xu: jnp.ndarray, s: float,
                     tail: tuple) -> jnp.ndarray:
     """Already-selected useful rows Xu (m*n, ...) -> (m, n, *tail) C blocks:
-    real part, digit extraction (or plain rounding), block reshape."""
+    real part, digit extraction (or plain rounding), block reshape; scoped
+    ``coded.extract``."""
     g = scheme.grid
-    if jnp.iscomplexobj(Xu):
-        Xu = Xu.real
-    if scheme.needs_digit_extraction:
-        C = digit_extract(Xu, s)
-    else:
-        C = jnp.round(Xu)
-    return C.reshape(g.m, g.n, *tail)
+    with obs.stage(obs.EXTRACT):
+        if jnp.iscomplexobj(Xu):
+            Xu = Xu.real
+        if scheme.needs_digit_extraction:
+            C = digit_extract(Xu, s)
+        else:
+            C = jnp.round(Xu)
+        return C.reshape(g.m, g.n, *tail)
 
 
 def _extract_useful(scheme: Scheme, X: jnp.ndarray, s: float) -> jnp.ndarray:
     """X: (tau, br, bt) coefficients -> (m, n, br, bt) decoded C blocks."""
     idx = scheme.useful_z_exp().reshape(-1)  # (m*n,)
-    return _finish_extract(scheme, X[idx], s, X.shape[1:])
+    with obs.stage(obs.DECODE):
+        Xu = X[idx]
+    return _finish_extract(scheme, Xu, s, X.shape[1:])
 
 
 def decode(
@@ -98,8 +102,11 @@ def decode_masked(
     """Decode with a dynamic 0/1 survivor mask over all K workers (jit-safe).
 
     Requires sum(mask) >= tau; erased rows of Y_all may hold garbage.
+    The solve is scoped ``coded.decode``, the extraction ``coded.extract``.
     """
-    X = interpolate_masked(jnp.asarray(z_all), jnp.asarray(Y_all), mask, scheme.tau, ridge)
+    with obs.stage(obs.DECODE):
+        X = interpolate_masked(jnp.asarray(z_all), jnp.asarray(Y_all), mask,
+                               scheme.tau, ridge)
     return _extract_useful(scheme, X, s)
 
 
@@ -186,9 +193,12 @@ def decode_with_weights(scheme: Scheme, W: jnp.ndarray, Y_all: jnp.ndarray,
     Y_all: (K, br, bt) ALL worker outputs (garbage where erased) ->
     (m, n, br, bt).  No linear solve inside; erased workers have zero
     columns in W.  Because W is an operand (not a closed-over constant),
-    one compiled executable serves every concrete erasure pattern.
+    one compiled executable serves every concrete erasure pattern.  The
+    weighted sums are scoped ``coded.decode``, the extraction
+    ``coded.extract``.
     """
-    Xu = apply_weights(W, Y_all)                             # (mn, br, bt)
+    with obs.stage(obs.DECODE):
+        Xu = apply_weights(W, Y_all)                         # (mn, br, bt)
     return _finish_extract(scheme, Xu, s, Y_all.shape[1:])
 
 

@@ -12,6 +12,8 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = ["enable_x64", "x64_enabled", "precise_matmul_t",
            "sliced_matmul_t"]
 
@@ -52,11 +54,13 @@ def precise_matmul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
     float64 where XLA emulates it takes the int8-slice product
     (``sliced_matmul_t``); everything else is one ``Precision.HIGHEST``
-    einsum.
+    einsum, scoped ``coded.dots``.
     """
     if a.dtype == jnp.float64 and _emulated_f64():
         return sliced_matmul_t(a, b)
-    return jnp.einsum("vr,vt->rt", a, b, precision=jax.lax.Precision.HIGHEST)
+    with obs.stage(obs.DOTS):
+        return jnp.einsum("vr,vt->rt", a, b,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 def _int8_slices(x: jnp.ndarray):
@@ -64,21 +68,23 @@ def _int8_slices(x: jnp.ndarray):
 
     x[:, j] == 2^E[j] * sum_l slices[l, :, j] * 128^-(l+1), up to the last
     slice's 2^-56 of the column's largest entry.  Each slice is the rounded
-    integer part of the scaled remainder, so |slice| <= 64.
+    integer part of the scaled remainder, so |slice| <= 64.  Scoped
+    ``coded.slice``.
     """
-    m = jnp.max(jnp.abs(x), axis=0).astype(jnp.float32)
-    _, e = jnp.frexp(m)                       # max |x[:, j]| < 2^e[j]
-    E = e + 1                                 # |x / 2^E| < 1/2
-    u = x * jnp.ldexp(jnp.float32(1.0), -E).astype(x.dtype)
+    with obs.stage(obs.SLICE):
+        m = jnp.max(jnp.abs(x), axis=0).astype(jnp.float32)
+        _, e = jnp.frexp(m)                       # max |x[:, j]| < 2^e[j]
+        E = e + 1                                 # |x / 2^E| < 1/2
+        u = x * jnp.ldexp(jnp.float32(1.0), -E).astype(x.dtype)
 
-    def peel(u, _):
-        u = u * _SLICE_SCALE                  # exact: a power of two
-        q = jnp.round(u.astype(jnp.float32))  # |q| <= 64
-        return u - q.astype(x.dtype), q.astype(jnp.int8)   # |u| <= 1/2 + 2^-18
+        def peel(u, _):
+            u = u * _SLICE_SCALE                  # exact: a power of two
+            q = jnp.round(u.astype(jnp.float32))  # |q| <= 64
+            return u - q.astype(x.dtype), q.astype(jnp.int8)   # |u| <= 1/2 + 2^-18
 
-    # a loop, not unrolled: emulated-f64 steps compile slowly on a TPU
-    _, slices = jax.lax.scan(peel, u, None, length=_SLICES)
-    return slices, E
+        # a loop, not unrolled: emulated-f64 steps compile slowly on a TPU
+        _, slices = jax.lax.scan(peel, u, None, length=_SLICES)
+        return slices, E
 
 
 def sliced_matmul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -89,7 +95,8 @@ def sliced_matmul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     too, so rounding enters only in the final float sum over l + m.  Pairs
     with l + m > S weigh under 2^-63 of the scales' product and are left
     out.  Every scale stays inside float32's exponent range, which is all
-    the TPU's emulated float64 has.
+    the TPU's emulated float64 has.  The dots, their scaled sum and the
+    final scaling are scoped ``coded.dots``.
     """
     n = a.shape[0]
     if n > _MAX_CONTRACTION:
@@ -97,17 +104,18 @@ def sliced_matmul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
                          "slice sums could overflow")
     sa, ea = _int8_slices(a)
     sb, eb = _int8_slices(b)
-    out = None
-    for d in reversed(range(_SLICES + 1)):       # smallest terms first
-        # all pairs l + m == d as one dot, contracting over (l, n)
-        ls = range(max(0, d - _SLICES + 1), min(d, _SLICES - 1) + 1)
-        z = jax.lax.dot_general(
-            sa[ls[0]:ls[-1] + 1].reshape(-1, sa.shape[-1]),
-            sb[d - ls[-1]:d - ls[0] + 1][::-1].reshape(-1, sb.shape[-1]),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-        term = z.astype(a.dtype) * (_SLICE_SCALE ** -d)
-        out = term if out is None else out + term
-    # 128^-(l+1) * 128^-(m+1): the remaining 2^-14 goes into the scales
-    scale_r = jnp.ldexp(jnp.float32(1.0), ea - 7).astype(a.dtype)
-    scale_t = jnp.ldexp(jnp.float32(1.0), eb - 7).astype(a.dtype)
-    return out * scale_r[:, None] * scale_t[None, :]
+    with obs.stage(obs.DOTS):
+        out = None
+        for d in reversed(range(_SLICES + 1)):       # smallest terms first
+            # all pairs l + m == d as one dot, contracting over (l, n)
+            ls = range(max(0, d - _SLICES + 1), min(d, _SLICES - 1) + 1)
+            z = jax.lax.dot_general(
+                sa[ls[0]:ls[-1] + 1].reshape(-1, sa.shape[-1]),
+                sb[d - ls[-1]:d - ls[0] + 1][::-1].reshape(-1, sb.shape[-1]),
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+            term = z.astype(a.dtype) * (_SLICE_SCALE ** -d)
+            out = term if out is None else out + term
+        # 128^-(l+1) * 128^-(m+1): the remaining 2^-14 goes into the scales
+        scale_r = jnp.ldexp(jnp.float32(1.0), ea - 7).astype(a.dtype)
+        scale_t = jnp.ldexp(jnp.float32(1.0), eb - 7).astype(a.dtype)
+        return out * scale_r[:, None] * scale_t[None, :]

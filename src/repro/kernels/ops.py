@@ -12,8 +12,6 @@ and names the XLA worker stage (interpret mode off the chip runs f64).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,35 +46,6 @@ def _require_kernel_dtype(op: str, *xs) -> None:
         "the kernels use dtype=float32.")
 
 
-def _instrumented(op: str):
-    """Kernel timing hook: count every call, time the eager ones.
-
-    Nearly every ops.* call happens INSIDE a jit trace, where wall-clock
-    timing would measure tracing, not execution — those calls are only
-    counted (``kernel.call{op, traced=1}``).  Eager calls (operands are
-    concrete arrays, e.g. benches poking a kernel directly) get a real
-    span: the result is blocked on inside the span so the interval covers
-    device execution, attributable separately from surrounding XLA time.
-    The decorator is identity-cheap while obs is disabled — one global
-    check, no tracer inspection, bit-identical results.
-    """
-    def wrap(fn):
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            if not obs.enabled():
-                return fn(*args, **kwargs)
-            traced = any(isinstance(a, jax.core.Tracer)
-                         for a in jax.tree_util.tree_leaves(args))
-            obs.count("kernel.call", op=op, traced=int(traced))
-            if traced:
-                return fn(*args, **kwargs)
-            with obs.span(f"kernel.{op}", lane="kernels"):
-                out = fn(*args, **kwargs)
-                return jax.block_until_ready(out)
-        return inner
-    return wrap
-
-
 def _pow2_tile(cap: int, dim: int) -> int:
     """Clamp a tile size to the next pow2 >= dim (floor 8), capped at cap."""
     return min(cap, int(2 ** np.ceil(np.log2(max(dim, 8)))))
@@ -90,7 +59,6 @@ def _pad_last(x: jnp.ndarray, multiple: int) -> jnp.ndarray:
     return jnp.pad(x, width)
 
 
-@_instrumented("encode")
 def encode(coeff: jnp.ndarray, blocks: jnp.ndarray, *, e_blk: int = 2048) -> jnp.ndarray:
     """coeff: (K, P), blocks: (P, E) -> (K, E) coded blocks (flattened)."""
     if jnp.iscomplexobj(coeff):
@@ -105,7 +73,6 @@ def encode(coeff: jnp.ndarray, blocks: jnp.ndarray, *, e_blk: int = 2048) -> jnp
     return out[:, :E]
 
 
-@_instrumented("decode")
 def decode(W: jnp.ndarray, Y: jnp.ndarray, s: float, *, extract: bool = True,
            e_blk: int = 2048) -> jnp.ndarray:
     """W: (mn, tau), Y: (tau, E) -> (mn, E) decoded + digit-extracted."""
@@ -121,7 +88,6 @@ def decode(W: jnp.ndarray, Y: jnp.ndarray, s: float, *, extract: bool = True,
     return out[:, :E]
 
 
-@_instrumented("decode_partial")
 def decode_partial(W_stack: jnp.ndarray, Y: jnp.ndarray, s: float, *,
                    extract: bool = True, e_blk: int = 2048) -> jnp.ndarray:
     """W_stack: (Q, mn, K), Y: (Q, K, Ec) -> (Q, mn, Ec) per-chunk decode.
@@ -143,7 +109,6 @@ def decode_partial(W_stack: jnp.ndarray, Y: jnp.ndarray, s: float, *,
     return out[:, :, :Ec]
 
 
-@_instrumented("fused_worker")
 def fused_worker(
     coeff_a: jnp.ndarray,
     coeff_b: jnp.ndarray,
@@ -189,7 +154,6 @@ def fused_worker(
     return out[:, :r, :t]
 
 
-@_instrumented("matmul_t")
 def matmul_t(A: jnp.ndarray, B: jnp.ndarray, *, bm: int = 128, bn: int = 128,
              bk: int = 512, out_dtype=None) -> jnp.ndarray:
     """A: (v, r), B: (v, t) -> A^T B with MXU tiling; pads to tile multiples."""

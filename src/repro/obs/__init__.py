@@ -1,4 +1,4 @@
-"""``repro.obs`` — spans, metrics, and exporters for the coded stack.
+"""``repro.obs`` — spans, metrics, stage scopes and exporters for the coded stack.
 
 One process-wide :class:`ObsSession` holds a metrics registry, a span
 recorder, and an injectable clock.  Instrumented call sites use the
@@ -7,6 +7,21 @@ module-level conveniences (:func:`count`, :func:`observe`, :func:`span`,
 called — the disabled fast path is one global ``None`` check, so the
 instrumented code paths return bit-identical results with observability
 off.
+
+Spans stamped from the real clock (the default ``MONOTONIC``) also open a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profile is
+being recorded they land in its ``.xplane.pb`` on the host's threads, on
+the same clock as the device's ops.  Spans on a simulated clock (a
+:class:`SettableClock`, as the serve tier installs) and pre-timed
+:func:`emit_span` records stay in the recorder only: their seconds are
+not the profiler's.
+
+Device work is named by :func:`stage`: a ``jax.named_scope`` from
+:data:`STAGES` around each stage of the traced coded pipeline.  It costs
+nothing at run time; the name lands in each op's ``op_name`` metadata,
+which a profile keeps with each executable's HLO, so a profile's op
+events can be attributed to the stage.  An op belongs to the innermost
+``coded.*`` scope in its ``op_name``.
 
 Enable programmatically::
 
@@ -24,6 +39,8 @@ from __future__ import annotations
 import os
 from typing import Optional, Sequence
 
+import jax
+
 from repro.obs.clock import MONOTONIC, Clock, SettableClock
 from repro.obs.metrics import DEFAULT_BUCKETS, MetricsRegistry
 from repro.obs.spans import NULL_SPAN, Span, SpanRecorder, span_id_for
@@ -33,7 +50,31 @@ __all__ = [
     "MetricsRegistry", "DEFAULT_BUCKETS", "span_id_for",
     "enable", "disable", "enabled", "session",
     "count", "gauge", "observe", "span", "emit_span", "use_clock",
+    "stage", "STAGES", "ENCODE", "SLICE", "DOTS", "ALLGATHER", "DECODE",
+    "EXTRACT", "RECOMPOSE", "CALL", "PANEL", "LAUNCH",
 ]
+
+# -- names of the coded pipeline's stages (device) and host spans -----------
+
+ENCODE = "coded.encode"        # block decomposition, coefficient sums of A, B
+SLICE = "coded.slice"          # Ozaki split of a worker's operands into int8
+DOTS = "coded.dots"            # the worker product proper
+ALLGATHER = "coded.allgather"  # the exchange of worker products between chips
+DECODE = "coded.decode"        # erasure and the decode's weighted sums
+EXTRACT = "coded.extract"      # digit extraction or rounding
+RECOMPOSE = "coded.recompose"  # layout of C
+STAGES = (ENCODE, SLICE, DOTS, ALLGATHER, DECODE, EXTRACT, RECOMPOSE)
+
+CALL = "coded.call"            # one CodedMatmul.__call__
+PANEL = "coded.panel"          # its decode-panel lookup and upload
+LAUNCH = "coded.launch"        # its executable launch (operand moves included)
+
+
+def stage(name: str):
+    """``jax.named_scope(name)`` for one of :data:`STAGES`."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; stages: {STAGES}")
+    return jax.named_scope(name)
 
 
 class ObsSession:

@@ -7,6 +7,10 @@ replays) install a :class:`SettableClock` instead and advance it to the
 loop's own simulated ``now`` — every context-manager span then stamps
 SIMULATED seconds, so two runs of the same (spec, scenario, seed) recipe
 produce byte-identical span streams.
+
+Only spans on ``MONOTONIC`` also go to the JAX profiler's trace (as
+``TraceAnnotation`` records on the profiler's own clock); spans on a
+:class:`SettableClock` stay in the span recorder.
 """
 from __future__ import annotations
 

@@ -13,6 +13,13 @@ Two ways to produce one:
   pre-timed intervals, e.g. the serve tier's simulated pipeline stages
   whose start/end come from the schedule, not from wall time.
 
+A context-manager span stamped from the real clock (``MONOTONIC``) also
+enters a ``jax.profiler.TraceAnnotation`` of its name and attrs while a
+profile is being recorded, so the profile holds it beside the device's
+ops, on the profiler's clock.  Spans on any other clock (a simulated
+``SettableClock``) and ``emit`` records are not annotated: their times
+are not the profiler's.
+
 Span IDs are deterministic: the recorder numbers spans in creation
 order, and :func:`span_id_for` derives stable seed-keyed IDs for records
 that must survive replay byte-identically (serve traces, chaos traces).
@@ -23,6 +30,8 @@ import hashlib
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.clock import MONOTONIC, Clock
 
@@ -65,7 +74,7 @@ class _OpenSpan:
     """Context manager for an in-progress span (returned by ``span()``)."""
 
     __slots__ = ("_rec", "name", "track", "lane", "attrs", "sid",
-                 "start_s", "_parent")
+                 "start_s", "_parent", "_note")
 
     def __init__(self, rec: "SpanRecorder", name: str, track: str,
                  lane: str, attrs: Dict[str, str]):
@@ -77,13 +86,19 @@ class _OpenSpan:
         self.sid = -1
         self.start_s = 0.0
         self._parent: Optional[int] = None
+        self._note = None
 
     def __enter__(self) -> "_OpenSpan":
+        if self._rec.clock is MONOTONIC and TraceAnnotation.is_enabled():
+            self._note = TraceAnnotation(self.name, **self.attrs)
+            self._note.__enter__()
         self.sid, self._parent, self.start_s = self._rec._open(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._rec._close(self, ok=exc_type is None)
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         return False  # never swallow the exception
 
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.api import (
     CodedMatmulPlan,
     _coeff_dtype,
@@ -109,8 +110,9 @@ class LocalExecutor:
         g = plan.scheme.grid
 
         def products(A, B):
-            a_blocks = block_decompose(A.astype(dtype), g.p, g.m)
-            b_blocks = block_decompose(B.astype(dtype), g.p, g.n)
+            with obs.stage(obs.ENCODE):
+                a_blocks = block_decompose(A.astype(dtype), g.p, g.m)
+                b_blocks = block_decompose(B.astype(dtype), g.p, g.n)
             return self.worker_products(plan, a_blocks, b_blocks)  # (K, br, bt)
 
         def stages(A, B, mask):
@@ -118,10 +120,11 @@ class LocalExecutor:
             # stage 3 ERASE: zero failed workers' outputs (decode weights
             # also annihilate them; the multiply keeps parity with the mesh
             # pipeline where erased devices genuinely emit garbage).
-            return Y * mask.astype(Y.dtype)[:, None, None]
+            return _erase(Y, mask)
 
         def finish(C_blocks, r, t):
-            return unpad(block_recompose(C_blocks), (r, t)).astype(dtype)
+            with obs.stage(obs.RECOMPOSE):
+                return unpad(block_recompose(C_blocks), (r, t)).astype(dtype)
 
         if kind == "products":
             # stage 1+2 only (encode + worker products), for split-stage
@@ -148,8 +151,9 @@ class LocalExecutor:
 
         def fn(A, B, mask):
             Y = stages(A, B, mask)
-            C_blocks = decode_masked(plan.scheme, z_all, Y,
-                                     mask.astype(Y.real.dtype), plan.s)
+            with obs.stage(obs.DECODE):
+                C_blocks = decode_masked(plan.scheme, z_all, Y,
+                                         mask.astype(Y.real.dtype), plan.s)
             return finish(C_blocks, A.shape[1], B.shape[1])
 
         return fn
@@ -171,7 +175,7 @@ class LocalExecutor:
         if style == "decode":
 
             def fn(Y, mask, W):
-                Ym = Y * mask.astype(Y.dtype)[:, None, None]
+                Ym = _erase(Y, mask)
                 C_blocks = decode_with_weights(plan.scheme, W, Ym, plan.s)
                 return finish(C_blocks, r, t)
 
@@ -180,9 +184,10 @@ class LocalExecutor:
         z_all = jnp.asarray(plan.z_points)
 
         def fn(Y, mask):
-            Ym = Y * mask.astype(Y.dtype)[:, None, None]
-            C_blocks = decode_masked(plan.scheme, z_all, Ym,
-                                     mask.astype(Y.real.dtype), plan.s)
+            Ym = _erase(Y, mask)
+            with obs.stage(obs.DECODE):
+                C_blocks = decode_masked(plan.scheme, z_all, Ym,
+                                         mask.astype(Y.real.dtype), plan.s)
             return finish(C_blocks, r, t)
 
         return fn
@@ -208,12 +213,15 @@ class LocalExecutor:
                 bounds = chunk_bounds(Y.shape[1], Q)
                 parts = []
                 for c in range(Q):
-                    Yc = Y[:, bounds[c]:bounds[c + 1], :]
-                    Yc = Yc * chunk_masks[c].astype(Yc.dtype)[:, None, None]
+                    with obs.stage(obs.DECODE):
+                        Yc = _erase(Y[:, bounds[c]:bounds[c + 1], :],
+                                    chunk_masks[c])
+                        W_c = W_stack[c]
                     parts.append(decode_with_weights(
-                        plan.scheme, W_stack[c], Yc, plan.s))
-                return finish(jnp.concatenate(parts, axis=2),
-                              A.shape[1], B.shape[1])
+                        plan.scheme, W_c, Yc, plan.s))
+                with obs.stage(obs.RECOMPOSE):
+                    C_blocks = jnp.concatenate(parts, axis=2)
+                return finish(C_blocks, A.shape[1], B.shape[1])
 
             return fn
 
@@ -226,16 +234,18 @@ class LocalExecutor:
         def fn(A, B, progress):
             Y = products(A, B)                           # (K, br, bt)
             bounds = chunk_bounds(Y.shape[1], Q)
-            counts = jnp.floor(progress * Q + 1e-9)
+            with obs.stage(obs.DECODE):
+                counts = jnp.floor(progress * Q + 1e-9)
             parts = []
             for c in range(Q):
-                mask_c = ((c - k_idx) % Q < counts).astype(Y.real.dtype)
-                Yc = Y[:, bounds[c]:bounds[c + 1], :]
-                Yc = Yc * mask_c.astype(Yc.dtype)[:, None, None]
+                with obs.stage(obs.DECODE):
+                    mask_c = ((c - k_idx) % Q < counts).astype(Y.real.dtype)
+                    Yc = _erase(Y[:, bounds[c]:bounds[c + 1], :], mask_c)
                 parts.append(decode_masked(
                     plan.scheme, z_all, Yc, mask_c, plan.s))
-            return finish(jnp.concatenate(parts, axis=2),
-                          A.shape[1], B.shape[1])
+            with obs.stage(obs.RECOMPOSE):
+                C_blocks = jnp.concatenate(parts, axis=2)
+            return finish(C_blocks, A.shape[1], B.shape[1])
 
         return fn
 
@@ -263,12 +273,14 @@ class StagedKernelExecutor(LocalExecutor):
                          dtype=_coeff_dtype(a_blocks, plan))
         cb = jnp.asarray(plan.coeff_b.reshape(plan.K, p * n),
                          dtype=_coeff_dtype(b_blocks, plan))
-        a_tilde = kops.encode(ca, a_blocks.reshape(p * m, bv * br))
-        b_tilde = kops.encode(cb, b_blocks.reshape(p * n, bv * bt))
-        a_tilde = a_tilde.reshape(plan.K, bv, br)
-        b_tilde = b_tilde.reshape(plan.K, bv, bt)
-        return jnp.stack(
-            [kops.matmul_t(a_tilde[k], b_tilde[k]) for k in range(plan.K)])
+        with obs.stage(obs.ENCODE):
+            a_tilde = kops.encode(ca, a_blocks.reshape(p * m, bv * br))
+            b_tilde = kops.encode(cb, b_blocks.reshape(p * n, bv * bt))
+            a_tilde = a_tilde.reshape(plan.K, bv, br)
+            b_tilde = b_tilde.reshape(plan.K, bv, bt)
+        with obs.stage(obs.DOTS):
+            return jnp.stack([kops.matmul_t(a_tilde[k], b_tilde[k])
+                              for k in range(plan.K)])
 
 
 class FusedKernelExecutor(LocalExecutor):
@@ -279,6 +291,12 @@ class FusedKernelExecutor(LocalExecutor):
     def worker_products(self, plan, a_blocks, b_blocks):
         """One fused encode+product megakernel call for all K workers."""
         return fused_worker_products(plan, a_blocks, b_blocks)
+
+
+def _erase(Y: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    """Stage 3: zero the erased workers' rows of Y (K, ...); ``coded.decode``."""
+    with obs.stage(obs.DECODE):
+        return Y * mask.astype(Y.dtype)[:, None, None]
 
 
 # ---------------------------------------------------------------------------
@@ -308,27 +326,36 @@ def _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
 
     a_blocks (p, m, bv, br) / b_blocks (p, n, bv, bt) replicated; returns
     the (br, bt) block product this device contributes to the all-gather.
+    The encode is scoped ``coded.encode``, the product ``coded.dots``
+    (``coded.slice`` for the int8 split inside it).
     """
     p, m, bv, br = a_blocks.shape
     _, n, _, bt = b_blocks.shape
-    ca = jax.lax.dynamic_index_in_dim(coeff_a, k, axis=0)     # (1, p, m)
-    cb = jax.lax.dynamic_index_in_dim(coeff_b, k, axis=0)
+    with obs.stage(obs.ENCODE):
+        ca = jax.lax.dynamic_index_in_dim(coeff_a, k, axis=0)  # (1, p, m)
+        cb = jax.lax.dynamic_index_in_dim(coeff_b, k, axis=0)
     if use_kernels and fused:
         # stages 1+2 fused: coded tiles exist only in VMEM.
-        return kops.fused_worker(
-            ca.reshape(1, p * m), cb.reshape(1, p * n),
-            a_blocks.reshape(p * m, bv, br),
-            b_blocks.reshape(p * n, bv, bt))[0]               # (br, bt)
+        with obs.stage(obs.DOTS):
+            return kops.fused_worker(
+                ca.reshape(1, p * m), cb.reshape(1, p * n),
+                a_blocks.reshape(p * m, bv, br),
+                b_blocks.reshape(p * n, bv, bt))[0]           # (br, bt)
     if use_kernels:
-        a_tilde = kops.encode(ca.reshape(1, p * m),
-                              a_blocks.reshape(p * m, bv * br)).reshape(bv, br)
-        b_tilde = kops.encode(cb.reshape(1, p * n),
-                              b_blocks.reshape(p * n, bv * bt)).reshape(bv, bt)
-        return kops.matmul_t(a_tilde, b_tilde)                # (br, bt)
-    a_tilde = apply_weights(ca.reshape(1, p * m),
-                            a_blocks.reshape(p * m, bv, br))[0]
-    b_tilde = apply_weights(cb.reshape(1, p * n),
-                            b_blocks.reshape(p * n, bv, bt))[0]
+        with obs.stage(obs.ENCODE):
+            a_tilde = kops.encode(
+                ca.reshape(1, p * m),
+                a_blocks.reshape(p * m, bv * br)).reshape(bv, br)
+            b_tilde = kops.encode(
+                cb.reshape(1, p * n),
+                b_blocks.reshape(p * n, bv * bt)).reshape(bv, bt)
+        with obs.stage(obs.DOTS):
+            return kops.matmul_t(a_tilde, b_tilde)            # (br, bt)
+    with obs.stage(obs.ENCODE):
+        a_tilde = apply_weights(ca.reshape(1, p * m),
+                                a_blocks.reshape(p * m, bv, br))[0]
+        b_tilde = apply_weights(cb.reshape(1, p * n),
+                                b_blocks.reshape(p * n, bv, bt))[0]
     return precise_matmul_t(a_tilde, b_tilde)
 
 
@@ -340,23 +367,35 @@ def _mesh_worker_body(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW,
     ``have_panel`` (no solve below), else the (K,) evaluation points from
     which the masked normal equations are solved in-body (dynamic masks).
     """
-    k = jax.lax.axis_index(axis)
+    with obs.stage(obs.ENCODE):
+        k = jax.lax.axis_index(axis)
     p, m, bv, br = a_blocks.shape
     _, n, _, bt = b_blocks.shape
     y_local = _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
                                   use_kernels=use_kernels, fused=fused)
 
     # stage 3: erasure - zero out "failed" workers' outputs.
-    y_local = y_local * jax.lax.dynamic_index_in_dim(mask, k, 0, keepdims=False)
+    with obs.stage(obs.DECODE):
+        y_local = y_local * jax.lax.dynamic_index_in_dim(mask, k, 0,
+                                                         keepdims=False)
     # stage 4: all-gather and decode everywhere (each device keeps its C).
-    Y = jax.lax.all_gather(y_local, axis)                    # (K, br, bt)
-    if have_panel:
-        W = zW                                               # (mn, K), ready
-    else:
-        W = _decode_weights_masked(zW, mask, tau, useful)    # (mn, K)
-    X = apply_weights(W, Y)
-    C = digit_extract(X, s) if s is not None else jnp.round(X)
-    return C.reshape(m, n, br, bt)
+    with obs.stage(obs.ALLGATHER):
+        Y = jax.lax.all_gather(y_local, axis)                # (K, br, bt)
+    with obs.stage(obs.DECODE):
+        if have_panel:
+            W = zW                                           # (mn, K), ready
+        else:
+            W = _decode_weights_masked(zW, mask, tau, useful)  # (mn, K)
+        X = apply_weights(W, Y)
+    return _mesh_extract(X, s, (m, n, br, bt))
+
+
+def _mesh_extract(X, s, shape):
+    """Digit extraction (``s``) or rounding (None) of the decoded X into C
+    blocks of ``shape``; scoped ``coded.extract``."""
+    with obs.stage(obs.EXTRACT):
+        C = digit_extract(X, s) if s is not None else jnp.round(X)
+        return C.reshape(shape)
 
 
 def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
@@ -374,7 +413,8 @@ def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
     a plain Python loop inside the one shard_map program — progress stays
     strictly DATA and one executable serves every progress vector.
     """
-    k = jax.lax.axis_index(axis)
+    with obs.stage(obs.ENCODE):
+        k = jax.lax.axis_index(axis)
     p, m, bv, br = a_blocks.shape
     _, n, _, bt = b_blocks.shape
     y_local = _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
@@ -382,27 +422,27 @@ def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
 
     # stage 4: all-gather the UNMASKED products; stage 3 erasure happens
     # per chunk below (a slow worker's finished prefix still contributes).
-    Y = jax.lax.all_gather(y_local, axis)                    # (K, br, bt)
+    with obs.stage(obs.ALLGATHER):
+        Y = jax.lax.all_gather(y_local, axis)                # (K, br, bt)
     bounds = chunk_bounds(br, Q)
-    if not have_panel:
-        counts = jnp.floor(cm * Q + 1e-9)                    # (K,)
-        k_idx = jnp.arange(Y.shape[0])
-    parts = []
-    for c in range(Q):
-        if have_panel:
-            mask_c = cm[c]                                   # (K,)
-            W_c = zW[c]                                      # (mn, K)
-        else:
-            # worker k runs chunk (k + j) % Q as its j-th sub-task, so it
-            # holds chunk c iff ((c - k) mod Q) < its finished count.
-            mask_c = ((c - k_idx) % Q < counts).astype(Y.real.dtype)
-            W_c = _decode_weights_masked(zW, mask_c, tau, useful)
-        Yc = Y[:, bounds[c]:bounds[c + 1], :]
-        Yc = Yc * mask_c.astype(Yc.dtype)[:, None, None]
-        parts.append(apply_weights(W_c, Yc))
-    X = jnp.concatenate(parts, axis=1)                       # (mn, br, bt)
-    C = digit_extract(X, s) if s is not None else jnp.round(X)
-    return C.reshape(m, n, br, bt)
+    with obs.stage(obs.DECODE):
+        if not have_panel:
+            counts = jnp.floor(cm * Q + 1e-9)                # (K,)
+            k_idx = jnp.arange(Y.shape[0])
+        parts = []
+        for c in range(Q):
+            if have_panel:
+                mask_c = cm[c]                               # (K,)
+                W_c = zW[c]                                  # (mn, K)
+            else:
+                # worker k runs chunk (k + j) % Q as its j-th sub-task, so
+                # it holds chunk c iff ((c - k) mod Q) < its finished count.
+                mask_c = ((c - k_idx) % Q < counts).astype(Y.real.dtype)
+                W_c = _decode_weights_masked(zW, mask_c, tau, useful)
+            Yc = _erase(Y[:, bounds[c]:bounds[c + 1], :], mask_c)
+            parts.append(apply_weights(W_c, Yc))
+        X = jnp.concatenate(parts, axis=1)                   # (mn, br, bt)
+    return _mesh_extract(X, s, (m, n, br, bt))
 
 
 class MeshExecutor:
@@ -491,17 +531,21 @@ class MeshExecutor:
         )
 
         def run(A, B, mask, zW):
-            a_blocks = block_decompose(A.astype(dtype), g.p, g.m)
-            b_blocks = block_decompose(B.astype(dtype), g.p, g.n)
-            C_blocks = mapped(a_blocks, b_blocks, mask.astype(dtype),
-                              coeff_a, coeff_b, zW)
-            return unpad(block_recompose(C_blocks),
-                         (A.shape[1], B.shape[1])).astype(dtype)
+            with obs.stage(obs.ENCODE):
+                a_blocks = block_decompose(A.astype(dtype), g.p, g.m)
+                b_blocks = block_decompose(B.astype(dtype), g.p, g.n)
+            with obs.stage(obs.DECODE):
+                mask = mask.astype(dtype)
+                zW = zW.astype(dtype)
+            C_blocks = mapped(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW)
+            with obs.stage(obs.RECOMPOSE):
+                return unpad(block_recompose(C_blocks),
+                             (A.shape[1], B.shape[1])).astype(dtype)
 
         if is_partial and style == "partial":
 
             def fn(A, B, chunk_masks, W_stack):
-                return run(A, B, chunk_masks, W_stack.astype(dtype))
+                return run(A, B, chunk_masks, W_stack)
 
             return fn
 
@@ -516,7 +560,7 @@ class MeshExecutor:
         if kind == "concrete":
 
             def fn(A, B, mask, W):
-                return run(A, B, mask, W.astype(dtype))
+                return run(A, B, mask, W)
 
             return fn
 

@@ -9,7 +9,11 @@ separately:
 * batching: leading batch dimensions on A and/or B are lifted with vmap;
 * a jit-executable memo keyed by (backend, A.shape, B.shape, dtype,
   erasure-kind), so repeated serving calls - including calls with NEW
-  erasure patterns of the same kind - reuse one compiled executable.
+  erasure patterns of the same kind - reuse one compiled executable,
+  named ``coded_<kind>``;
+* the call's ``repro.obs`` spans: ``coded.call`` (with the facade's call
+  ordinal) around ``coded.panel`` (decode-panel lookup and upload) and
+  ``coded.launch`` (the executable's launch).
 
 Usage::
 
@@ -42,6 +46,16 @@ __all__ = ["CodedMatmul", "CacheGroup", "plan_token"]
 def _kind_label(kind) -> str:
     """Bounded-cardinality metric label for an executable kind."""
     return kind if isinstance(kind, str) else str(kind[0])
+
+
+def _named(fn, kind):
+    """``fn`` under the name ``coded_<kind>``, which its jit executable
+    takes (``jit_coded_concrete`` on a profile's module line)."""
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = "coded_" + _kind_label(kind).replace("-", "_")
+    return named
 
 
 def plan_token(plan: CodedMatmulPlan):
@@ -153,6 +167,7 @@ class CodedMatmul:
         if sub_tasks < 1:
             raise ValueError(f"need sub_tasks >= 1, got {sub_tasks}")
         self.sub_tasks = int(sub_tasks)
+        self._calls = 0
         self.plan = plan
         self.dtype = jnp.dtype(dtype)
         self._mesh = mesh
@@ -244,6 +259,13 @@ class CodedMatmul:
                 contraction mismatch, fewer than tau survivors, or a partial
                 progress vector that does not span the decoding system.
         """
+        self._calls += 1
+        with obs.span(obs.CALL, ordinal=self._calls):
+            return self._call(A, B, erasure, erased, survivors, mask,
+                              progress, sub_tasks)
+
+    def _call(self, A, B, erasure, erased, survivors, mask, progress,
+              sub_tasks) -> jnp.ndarray:
         Q = self.sub_tasks if sub_tasks is None else int(sub_tasks)
         if Q < 1:
             raise ValueError(f"need sub_tasks >= 1, got {Q}")
@@ -259,16 +281,17 @@ class CodedMatmul:
         B = jnp.asarray(B)
         self._check_operands(A, B)
         fn = self._get_executable(A, B, pattern.kind)
-        mask_arr = pattern.mask_array(self._mask_dtype())
+        args = (A, B, pattern.mask_array(self._mask_dtype()))
         if pattern.kind == "concrete":
             if pattern.n_survivors < self.plan.tau:
                 raise ValueError(
                     f"only {pattern.n_survivors} survivors < "
                     f"tau={self.plan.tau}: undecodable")
-            panel = self.panel_cache.get(pattern.mask)
-            W = jnp.asarray(panel.W, self._decode_dtype())
-            return fn(A, B, mask_arr, W)
-        return fn(A, B, mask_arr)
+            with obs.span(obs.PANEL):
+                panel = self.panel_cache.get(pattern.mask)
+                args += (jnp.asarray(panel.W, self._decode_dtype()),)
+        with obs.span(obs.LAUNCH):
+            return fn(*args)
 
     # -- split-stage serving -------------------------------------------------
     def worker_stage(self, A, B) -> jnp.ndarray:
@@ -364,11 +387,15 @@ class CodedMatmul:
             pattern.require_decodable(self.plan.tau)
             fn = self._get_executable(A, B, ("partial", pattern.Q))
             cm = pattern.chunk_masks
-            W_stack = self.panel_cache.get_partial(cm)
-            return fn(A, B, jnp.asarray(cm, self._mask_dtype()),
-                      jnp.asarray(W_stack, self._decode_dtype()))
-        fn = self._get_executable(A, B, ("partial-traced", pattern.Q))
-        return fn(A, B, pattern.progress_array(self._mask_dtype()))
+            with obs.span(obs.PANEL):
+                W_stack = jnp.asarray(self.panel_cache.get_partial(cm),
+                                      self._decode_dtype())
+            args = (A, B, jnp.asarray(cm, self._mask_dtype()), W_stack)
+        else:
+            fn = self._get_executable(A, B, ("partial-traced", pattern.Q))
+            args = (A, B, pattern.progress_array(self._mask_dtype()))
+        with obs.span(obs.LAUNCH):
+            return fn(*args)
 
     def _check_operands(self, A, B) -> None:
         if A.ndim < 2 or B.ndim < 2:
@@ -412,7 +439,7 @@ class CodedMatmul:
             n_data = 2 if kind[0] == "decode" else 1
             for _ in range(Y.ndim - 3):
                 base = jax.vmap(base, in_axes=(0, *([None] * n_data)))
-            fn = jax.jit(base)
+            fn = jax.jit(_named(base, kind))
         self._executables[key] = fn
         self._stats["builds"] += 1
         obs.count("runtime.executable.compile", kind=_kind_label(kind))
@@ -440,7 +467,7 @@ class CodedMatmul:
             in_axes = (0 if a_batch else None, 0 if b_batch else None,
                        *([None] * n_data))
             fn = jax.vmap(fn, in_axes=in_axes)
-        return jax.jit(fn)
+        return jax.jit(_named(fn, kind))
 
     # -- dtype policy -------------------------------------------------------
     def _mask_dtype(self):
