@@ -7,8 +7,9 @@ is WHERE and HOW the worker products are computed:
   reference  pure-jnp einsum oracle (ground truth, any backend, complex ok)
   staged     Pallas encode kernel -> HBM -> Pallas block matmul per worker
   fused      one Pallas megakernel per call; coded tiles live only in VMEM
-  mesh       shard_map over a worker axis: one device per worker, erasure
-             (binary or per-chunk partial) as a runtime mask, all-gather +
+  mesh       shard_map over a worker axis: one device per worker, A and B
+             row-sharded and all-gathered in the program, erasure (binary
+             or per-chunk partial) as a runtime mask, all-gather +
              replicated decode
 
 Executors expose ``make_pipeline(plan, kind, dtype)`` returning a pure
@@ -36,7 +37,7 @@ from typing import Callable, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core.api import (
@@ -88,6 +89,10 @@ class Executor(Protocol):
         that changes the compiled pipeline (mesh, axis, kernel flags)."""
         ...
 
+    def place_operands(self, A, B):  # pragma: no cover - protocol
+        """(A, B) in the layout the pipeline takes them in."""
+        ...
+
 
 class LocalExecutor:
     """Shared single-host pipeline; subclasses provide the worker stage."""
@@ -98,6 +103,10 @@ class LocalExecutor:
     def cache_token(self):
         """Executable-memo identity (the name: local executors are config-free)."""
         return self.name
+
+    def place_operands(self, A, B):
+        """(A, B) as they are: a local pipeline runs where they are."""
+        return A, B
 
     def worker_products(
         self, plan: CodedMatmulPlan, a_blocks: jnp.ndarray, b_blocks: jnp.ndarray
@@ -359,9 +368,30 @@ def _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
     return precise_matmul_t(a_tilde, b_tilde)
 
 
-def _mesh_worker_body(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW,
-                      *, tau, s, useful, axis, use_kernels, fused, have_panel):
-    """Per-device body.  a_blocks (p, m, bv, br) replicated; mask (K,).
+def _mesh_blocks(A, B, grid, *, axis, sharded):
+    """The operands as one device holds them -> all of their blocks,
+    (p, m, bv, br) and (p, n, bv, bt).
+
+    ``sharded`` operands hold this device's rows of v, which an all-gather
+    over ``axis`` (``coded.allgather``) joins into the whole operand: A's
+    first, so that B's exchange can run under A's encode.  The block
+    decomposition is ``coded.encode``.
+    """
+    blocks = []
+    for X, cols in ((A, grid.m), (B, grid.n)):
+        if sharded:
+            with obs.stage(obs.ALLGATHER):
+                X = jax.lax.all_gather(X, axis).reshape(-1, X.shape[-1])
+        with obs.stage(obs.ENCODE):
+            blocks.append(block_decompose(X, grid.p, cols))
+    return blocks
+
+
+def _mesh_worker_body(A, B, mask, coeff_a, coeff_b, zW,
+                      *, grid, sharded, tau, s, useful, axis, use_kernels,
+                      fused, have_panel):
+    """Per-device body.  A (v, r), B (v, t), row shards of them when
+    ``sharded`` (``_mesh_blocks``); mask (K,).
 
     ``zW`` is the decode operand: the ready (mn, K) weight panel when
     ``have_panel`` (no solve below), else the (K,) evaluation points from
@@ -369,6 +399,7 @@ def _mesh_worker_body(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW,
     """
     with obs.stage(obs.ENCODE):
         k = jax.lax.axis_index(axis)
+    a_blocks, b_blocks = _mesh_blocks(A, B, grid, axis=axis, sharded=sharded)
     p, m, bv, br = a_blocks.shape
     _, n, _, bt = b_blocks.shape
     y_local = _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
@@ -398,12 +429,13 @@ def _mesh_extract(X, s, shape):
         return C.reshape(shape)
 
 
-def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
-                       *, Q, tau, s, useful, axis, use_kernels, fused,
-                       have_panel):
+def _mesh_partial_body(A, B, cm, coeff_a, coeff_b, zW,
+                       *, Q, grid, sharded, tau, s, useful, axis, use_kernels,
+                       fused, have_panel):
     """Per-device partial-straggler body: ONE block product, Q chunk decodes.
 
-    Each device emits its block product once; after the all-gather every
+    Operands as in ``_mesh_worker_body``.  Each device emits its block
+    product once; after the all-gather every
     device decodes chunk-by-chunk.  ``cm`` is the (Q, K) chunk-availability
     matrix and ``zW`` the stacked (Q, mn, K) decode panels when
     ``have_panel`` (concrete progress); for traced progress ``cm`` is the
@@ -415,6 +447,7 @@ def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
     """
     with obs.stage(obs.ENCODE):
         k = jax.lax.axis_index(axis)
+    a_blocks, b_blocks = _mesh_blocks(A, B, grid, axis=axis, sharded=sharded)
     p, m, bv, br = a_blocks.shape
     _, n, _, bt = b_blocks.shape
     y_local = _mesh_local_product(a_blocks, b_blocks, coeff_a, coeff_b, k,
@@ -446,7 +479,19 @@ def _mesh_partial_body(a_blocks, b_blocks, cm, coeff_a, coeff_b, zW,
 
 
 class MeshExecutor:
-    """One worker per device along a mesh axis; erasure is a runtime mask."""
+    """One worker per device along a mesh axis; erasure is a runtime mask.
+
+    Operand layout.  A (v, r) and B (v, t) enter the program as row shards
+    of their contraction axis v over ``axis`` (``P(axis, None)``, at
+    position -2 of a batched shape; replicated along any other mesh axis),
+    and the program all-gathers the shards over the chips' interconnect
+    before the encode.  Where the K workers do not split v evenly, the
+    operands enter whole on every device.  The shape alone decides
+    (``operand_layout``).  ``place_operands`` puts operands that live off
+    the mesh, such as a single-device array, into that layout; operands
+    already on the mesh's devices keep theirs.  The worker products'
+    all-gather, the decode on every device and the replicated C follow.
+    """
 
     name = "mesh"
     supports_batching = True  # vmap lifts through shard_map
@@ -463,6 +508,36 @@ class MeshExecutor:
     def cache_token(self):
         """Executable-memo identity: name + mesh + axis + kernel flags."""
         return (self.name, self.mesh, self.axis, self.use_kernels, self.fused)
+
+    def operand_layout(self, v: int) -> str:
+        """``"sharded"`` where the workers split the contraction length
+        ``v`` evenly, else ``"replicated"``."""
+        return "sharded" if v % self.mesh.shape[self.axis] == 0 else "replicated"
+
+    def operand_sharding(self, shape) -> NamedSharding:
+        """Where an operand of ``shape`` (*batch, v, cols) enters the program."""
+        if self.operand_layout(shape[-2]) == "replicated":
+            return NamedSharding(self.mesh, P())
+        return NamedSharding(self.mesh,
+                             P(*[None] * (len(shape) - 2), self.axis, None))
+
+    def place_operands(self, A, B):
+        """(A, B) in the program's layout; counts ``mesh.operands{layout}``.
+
+        Operands that live off the mesh are moved explicitly, so that each
+        device receives only its rows; traced operands and arrays already
+        on the mesh's devices are left to the program.
+        """
+        obs.count("mesh.operands", layout=self.operand_layout(A.shape[-2]))
+        devices = set(self.mesh.devices.flat)
+
+        def place(X):
+            if isinstance(X, jax.core.Tracer) or (
+                    isinstance(X, jax.Array) and X.sharding.device_set == devices):
+                return X
+            return jax.device_put(X, self.operand_sharding(X.shape))
+
+        return place(A), place(B)
 
     def make_pipeline(self, plan: CodedMatmulPlan, kind, dtype) -> Callable:
         """The shard_map pipeline (one device per worker) for ``kind``.
@@ -515,29 +590,30 @@ class MeshExecutor:
         if is_partial:
             style, Q = kind
             body = partial(
-                _mesh_partial_body, Q=Q, tau=plan.tau, s=s, useful=useful,
-                axis=self.axis, use_kernels=self.use_kernels,
+                _mesh_partial_body, Q=Q, grid=g, tau=plan.tau, s=s,
+                useful=useful, axis=self.axis, use_kernels=self.use_kernels,
                 fused=self.fused, have_panel=(style == "partial"))
         else:
             body = partial(
-                _mesh_worker_body, tau=plan.tau, s=s, useful=useful,
+                _mesh_worker_body, grid=g, tau=plan.tau, s=s, useful=useful,
                 axis=self.axis, use_kernels=self.use_kernels,
                 fused=self.fused, have_panel=(kind == "concrete"))
-        mapped = shard_map_compat(
-            body,
-            mesh=self.mesh,
-            in_specs=(P(), P(), P(), P(), P(), P()),   # replicated operands
-            out_specs=P(),
-        )
 
         def run(A, B, mask, zW):
+            spec = self.operand_sharding(A.shape).spec
+            mapped = shard_map_compat(
+                partial(body, sharded=(spec != P())),
+                mesh=self.mesh,
+                in_specs=(spec, spec, P(), P(), P(), P()),
+                out_specs=P(),
+            )
             with obs.stage(obs.ENCODE):
-                a_blocks = block_decompose(A.astype(dtype), g.p, g.m)
-                b_blocks = block_decompose(B.astype(dtype), g.p, g.n)
+                A = A.astype(dtype)
+                B = B.astype(dtype)
             with obs.stage(obs.DECODE):
                 mask = mask.astype(dtype)
                 zW = zW.astype(dtype)
-            C_blocks = mapped(a_blocks, b_blocks, mask, coeff_a, coeff_b, zW)
+            C_blocks = mapped(A, B, mask, coeff_a, coeff_b, zW)
             with obs.stage(obs.RECOMPOSE):
                 return unpad(block_recompose(C_blocks),
                              (A.shape[1], B.shape[1])).astype(dtype)

@@ -13,7 +13,8 @@ separately:
   named ``coded_<kind>``;
 * the call's ``repro.obs`` spans: ``coded.call`` (with the facade's call
   ordinal) around ``coded.panel`` (decode-panel lookup and upload) and
-  ``coded.launch`` (the executable's launch).
+  ``coded.launch`` (the executor's ``place_operands`` and the
+  executable's launch).
 
 Usage::
 
@@ -291,7 +292,7 @@ class CodedMatmul:
                 panel = self.panel_cache.get(pattern.mask)
                 args += (jnp.asarray(panel.W, self._decode_dtype()),)
         with obs.span(obs.LAUNCH):
-            return fn(*args)
+            return fn(*self._executor.place_operands(A, B), *args[2:])
 
     # -- split-stage serving -------------------------------------------------
     def worker_stage(self, A, B) -> jnp.ndarray:
@@ -395,7 +396,7 @@ class CodedMatmul:
             fn = self._get_executable(A, B, ("partial-traced", pattern.Q))
             args = (A, B, pattern.progress_array(self._mask_dtype()))
         with obs.span(obs.LAUNCH):
-            return fn(*args)
+            return fn(*self._executor.place_operands(A, B), *args[2:])
 
     def _check_operands(self, A, B) -> None:
         if A.ndim < 2 or B.ndim < 2:

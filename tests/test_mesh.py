@@ -6,6 +6,8 @@ import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
@@ -180,6 +182,153 @@ C0 = np.asarray(uncoded_matmul(A, B))
 prog = np.ones(plan.K); prog[0] = prog[1] = 0.5
 C = np.asarray(cm(A, B, progress=prog, sub_tasks=2))
 assert np.array_equal(C, C0)
+print("OK")
+""")
+        assert "OK" in out
+
+
+class TestMeshOperandLayout:
+    """A and B enter the mesh program as row shards of v over ``model`` and
+    are all-gathered inside it; where K does not split v, whole.  The
+    results stay bit-identical to the reference backend."""
+
+    SCHEMES = {
+        # scheme: (p, m, n, K, v); v splits over the K workers
+        "bec": (2, 2, 1, 4, 24),
+        "polycode": (2, 2, 1, 6, 24),
+    }
+
+    @pytest.mark.parametrize("scheme", sorted(SCHEMES))
+    def test_sharded_bit_identical_to_reference(self, scheme):
+        p, m, n, K, v = self.SCHEMES[scheme]
+        out = run_child(f"""
+import itertools
+import jax; jax.config.update("jax_enable_x64", True)
+import jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType, Mesh
+from repro.core import make_plan
+from repro.runtime import CodedMatmul, MeshExecutor
+
+K, v = {K}, {v}
+plan = make_plan("{scheme}", {p}, {m}, {n}, K=K, L=v * 4 * 4 + 1,
+                 points="chebyshev")
+mesh = Mesh(np.array(jax.devices()[:K]).reshape(1, K), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
+ex = MeshExecutor(mesh, use_kernels=False)
+assert ex.operand_layout(v) == "sharded"
+cm = CodedMatmul(plan, ex, dtype=jnp.float64)
+ref = CodedMatmul(plan, "reference", dtype=jnp.float64)
+rng = np.random.default_rng(3)
+A = jnp.asarray(rng.integers(-4, 5, size=(v, 12)), jnp.float64)
+B = jnp.asarray(rng.integers(-4, 5, size=(v, 10)), jnp.float64)
+C0 = np.asarray(A).T @ np.asarray(B)
+traced = jax.jit(lambda a, b, mk: cm(a, b, mask=mk))
+patterns = [e for k in range(K - plan.tau + 1)
+            for e in itertools.combinations(range(K), k)]
+for erased in patterns:
+    mask = np.ones(K); mask[list(erased)] = 0.0
+    Cr = np.asarray(ref(A, B, erased=list(erased)))
+    assert np.array_equal(Cr, C0), erased
+    Cc = cm(A, B, erased=list(erased))
+    assert Cc.sharding.is_fully_replicated, Cc.sharding
+    assert np.array_equal(np.asarray(Cc), Cr), ("concrete", erased)
+    Ct = np.asarray(traced(A, B, jnp.asarray(mask)))
+    assert np.array_equal(Ct, Cr), ("traced", erased)
+for Q in (1, 2):
+    prog = np.ones(K); prog[0] = (Q - 1) / Q
+    Cp = np.asarray(cm(A, B, progress=prog, sub_tasks=Q))
+    assert np.array_equal(Cp, np.asarray(ref(A, B, progress=prog, sub_tasks=Q))), Q
+    assert np.array_equal(Cp, C0), Q
+print("OK", len(patterns))
+""")
+        assert "OK" in out
+
+    def test_single_device_operands_enter_row_sharded(self):
+        out = run_child("""
+import jax; jax.config.update("jax_enable_x64", True)
+import jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from repro.core import make_plan
+from repro.runtime import CodedMatmul, MeshExecutor
+
+mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 4), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
+plan = make_plan("bec", 2, 2, 1, K=4, L=32 * 16 + 1, points="chebyshev")
+ex = MeshExecutor(mesh, use_kernels=False)
+cm = CodedMatmul(plan, ex, dtype=jnp.float64)
+rng = np.random.default_rng(4)
+A = jax.device_put(jnp.asarray(rng.integers(-4, 5, size=(32, 12)), jnp.float64),
+                   jax.devices()[0])
+B = jnp.asarray(rng.integers(-4, 5, size=(32, 10)), jnp.float64)  # uncommitted
+C = cm(A, B, erased=[1, 2])
+assert np.array_equal(np.asarray(C), np.asarray(A).T @ np.asarray(B))
+pa, pb = ex.place_operands(A, B)
+for X in (pa, pb):
+    assert X.sharding.spec == P("model", None), X.sharding
+    assert {s.data.shape[0] for s in X.addressable_shards} == {8}
+mask = jnp.asarray([1.0, 0.0, 0.0, 1.0])
+W = jnp.asarray(cm.panel_cache.get(np.asarray(mask)).W)
+(fn,) = cm._executables.values()
+# the layout the program itself asks for, with no sharding given
+shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (A, B, mask, W)]
+compiled = fn.lower(*shapes).compile()
+rows = NamedSharding(mesh, P("model", None))
+for s in compiled.input_shardings[0][:2]:
+    assert s.is_equivalent_to(rows, 2), s
+# operands already on the mesh's devices keep their layout
+on_mesh = jax.device_put(A, NamedSharding(mesh, P()))
+kept_a, kept_b = ex.place_operands(on_mesh, pb)
+assert kept_a is on_mesh and kept_b is pb
+assert np.array_equal(np.asarray(cm(on_mesh, B, erased=[1, 2])), np.asarray(C))
+print("OK")
+""")
+        assert "OK" in out
+
+    def test_uneven_and_batched_operands_exact_and_counted(self):
+        out = run_child("""
+import jax; jax.config.update("jax_enable_x64", True)
+import jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
+from repro import obs
+from repro.core import make_plan
+from repro.runtime import CodedMatmul, MeshExecutor
+
+mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 4), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
+plan = make_plan("bec", 2, 2, 1, K=4, L=34 * 16 + 1, points="chebyshev")
+ex = MeshExecutor(mesh, use_kernels=False)
+cm = CodedMatmul(plan, ex, dtype=jnp.float64)
+ref = CodedMatmul(plan, "reference", dtype=jnp.float64)
+rng = np.random.default_rng(5)
+reg = obs.enable(fresh=True).registry
+
+def ints(*shape):
+    return jnp.asarray(rng.integers(-4, 5, size=shape), jnp.float64)
+
+# v = 34: the workers do not split it, so A and B enter whole
+A, B = ints(34, 12), ints(34, 10)
+assert ex.operand_layout(34) == "replicated"
+assert ex.operand_sharding(A.shape).spec == P()
+C0 = np.asarray(A).T @ np.asarray(B)
+for erased in ([], [0, 3], [1, 2]):
+    C = np.asarray(cm(A, B, erased=erased))
+    assert np.array_equal(C, C0) and np.array_equal(
+        C, np.asarray(ref(A, B, erased=erased))), erased
+assert np.array_equal(np.asarray(cm(A, B, progress=[1, 1, 0.5, 0.5],
+                                    sub_tasks=2)), C0)
+assert reg.value("mesh.operands", layout="replicated") == 4
+assert reg.value("mesh.operands", layout="sharded") is None
+
+# batched A (2, 32, r): v at position -2 is sharded, the batch is not
+Ab, B2 = ints(2, 32, 12), ints(32, 10)
+assert ex.operand_sharding(Ab.shape).spec == P(None, "model", None)
+Cb = cm(Ab, B2, erased=[2])
+for i in range(2):
+    assert np.array_equal(np.asarray(Cb[i]), np.asarray(Ab[i]).T @ np.asarray(B2)), i
+assert np.array_equal(np.asarray(Cb), np.asarray(ref(Ab, B2, erased=[2])))
+cm(Ab[0], B2, erased=[0, 1])
+assert reg.value("mesh.operands", layout="sharded") == 2
+assert reg.value("mesh.operands", layout="replicated") == 4
 print("OK")
 """)
         assert "OK" in out
