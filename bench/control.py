@@ -4,12 +4,15 @@
         --seeds <n> ... --control-seeds <n> ...
 
 Each of ``--seeds`` is one run of the program as the configuration states
-it: its ``max_abs_err`` is a lower reading.  Each of ``--control-seeds`` is
-one run of the control, the program's own float32 path switched on in place
-of float64: its ``max_abs_err`` is an upper reading, and the control has to
-come out not correct.  All runs share one process and one facade per
-precision, so each precision compiles once.  One JSON line per run on
-stdout, then a summary line.  The benchmark's own runs never run this.
+it: each number its check compares (``max_abs_err`` for the coded matmul)
+is a lower reading.  Each of ``--control-seeds`` is one run of the control,
+the program's own path one precision below, which the cell's system module
+names (``CONTROL_DTYPE``; float32 in place of float64 for the coded
+matmul): its numbers are upper readings, and the control has to come out
+not correct.  All runs share one process and one system per precision,
+built through the cell's system module, so each precision compiles once.
+One JSON line per run on stdout, then a summary line.  The benchmark's own
+runs never run this.
 """
 import argparse
 import json
@@ -30,25 +33,25 @@ def main(argv=None) -> int:
 
     from bench.cell import run_cell
     from bench.spec import cell_spec, load_benchmark
-    from bench.system import build
 
     cell = cell_spec(load_benchmark(ROOT), args.workload)
     devices = chips_for(cell)
     if devices is None:
         return 1
+    system = cell["system"]
     readings = {}
-    for dtype, seeds in (("float64", args.seeds), ("float32", args.control_seeds)):
+    for dtype, seeds in ((None, args.seeds), (system.CONTROL_DTYPE, args.control_seeds)):
         if not seeds:
             continue
-        sut = build(cell["config"], cell["traffic"], devices, dtype)
+        sut = system.build(cell["config"], cell["traffic"], devices, dtype)
         for seed in seeds:
             res = run_cell(cell, seed, args.seconds, False, devices=devices,
                            t_start=time.perf_counter(), sut=sut)
-            err = res["checks"]["max_abs_err"]["value"]
-            readings.setdefault(dtype, []).append(err)
-            print(json.dumps({"dtype": dtype, "seed": seed, "correct": res["correct"],
-                              "attempted": res["attempted"], "max_abs_err": err,
-                              "metrics": res["metrics"]}), flush=True)
+            got = {k: c["value"] for k, c in res["checks"].items()}
+            readings.setdefault(str(sut.dtype), []).append(got)
+            print(json.dumps({"dtype": str(sut.dtype), "seed": seed,
+                              "correct": res["correct"], "attempted": res["attempted"],
+                              "checks": got, "metrics": res["metrics"]}), flush=True)
     print(json.dumps({"workload": args.workload, "readings": readings}))
     return 0
 

@@ -14,9 +14,10 @@ from __future__ import annotations
 import dataclasses
 import re
 from collections import defaultdict
+from pathlib import Path
 
 __all__ = [
-    "Event", "Trace", "load", "short_name", "union", "window", "covered",
+    "Event", "Trace", "load", "load_dir", "short_name", "union", "window", "covered",
     "calls_in", "busy_ns",
     "idle_gaps", "gap_label", "module_ns", "matching_ns", "self_times",
     "breakdown",
@@ -78,6 +79,15 @@ def load(path) -> Trace:
                     dest.append(Event(e.name, e.start_ns, e.end_ns))
     return Trace(*(dict(lines[k]) for k in (OP_LINE, ASYNC_LINE, MODULE_LINE)),
                  host)
+
+
+def load_dir(directory) -> Trace:
+    """``load`` the newest ``.xplane.pb`` that ``jax.profiler`` wrote under
+    ``directory``."""
+    found = sorted(Path(directory).rglob("*.xplane.pb"))
+    if not found:
+        raise RuntimeError(f"the profiler wrote no trace under {directory}")
+    return load(found[-1])
 
 
 def short_name(hlo: str) -> str:
